@@ -52,7 +52,8 @@ print(json.dumps({
 
 
 def structural():
-    env = dict(os.environ)
+    # placeholder host devices only: the child never competes for a chip
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
     out = subprocess.run([sys.executable, "-c", _STRUCTURAL_SNIPPET],

@@ -1,4 +1,7 @@
 import os
+# 512 placeholder host devices, pinned to the CPU backend so a dry-run on a
+# machine with a chip neither takes the chip nor builds its mesh from it
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 

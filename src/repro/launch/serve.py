@@ -41,6 +41,7 @@ from repro.configs import ARCH_IDS, get_config, get_smoke
 from repro.core.goodput import Layer, Phase
 from repro.core.ledger import GoodputLedger
 from repro.models import model, transformer
+from repro.runtime.compile_cache import enable_persistent_cache
 
 
 @dataclasses.dataclass
@@ -268,12 +269,12 @@ def run_continuous_server(cfg, reqs: List[Request], batch: int,
                           slo_ttft: float, slo_tpot: float,
                           kv_block_tokens: int = 0,
                           clock: Callable[[], float] = time.monotonic,
-                          executor_kind: str = "auto") -> dict:
-    """Drive the continuous engine over the real model and return its
-    ServeReport dict.  ``executor_kind``: "batched" decodes every live
-    slot in one jitted call over the paged KV pool, "slot" runs the
-    per-slot batch-1 fallback, "auto" picks batched when the family
-    supports paged decode."""
+                          executor_kind: str = "auto") -> Tuple[object, dict]:
+    """Drive the continuous engine over the real model and return
+    ``(executor, ServeReport dict)``.  ``executor_kind``: "batched"
+    decodes every live slot in one jitted call over the paged KV pool,
+    "slot" runs the per-slot batch-1 fallback, "auto" picks batched when
+    the family supports paged decode."""
     from repro.models import model as _model
     from repro.serve import (ContinuousServeEngine, PagedKVCache,
                              ServeRequest, ServeSLO)
@@ -316,7 +317,7 @@ def run_continuous_server(cfg, reqs: List[Request], batch: int,
         r.t_first, r.t_done = sr.t_first, sr.t_done
     out = report.as_dict()
     out["arch"] = cfg.name
-    return out
+    return executor, out
 
 
 def main(argv=None):
@@ -351,6 +352,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_persistent_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     clock = TickClock(dt=args.tick_dt) if args.tick_dt > 0 \
         else time.monotonic
@@ -375,7 +377,7 @@ def main(argv=None):
             for i in range(args.requests)]
 
     if args.engine == "continuous":
-        out = run_continuous_server(
+        _, out = run_continuous_server(
             cfg, reqs, args.batch, args.max_new, args.prompt_len,
             slo_ttft=args.slo_ttft, slo_tpot=args.slo_tpot, clock=clock,
             executor_kind=args.executor)

@@ -4,8 +4,8 @@
         --steps 200 --batch 8 --seq 128
 
 Runs the MPG-instrumented orchestrator (checkpoint/restart, async ckpt,
-AOT cache) on CPU for smoke-scale configs; on a real TPU slice the same
-entry point builds the production mesh and sharded step function.
+AOT cache) on the default backend: one device, no mesh.  The sharded step
+on a device mesh is built by ``repro.launch.strategy.jit_train_step``.
 """
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ import tempfile
 
 from repro.configs import ARCH_IDS, get_config, get_smoke
 from repro.core.goodput import compute_goodput, rg_breakdown
+from repro.runtime.compile_cache import enable_persistent_cache
 from repro.runtime.orchestrator import Orchestrator, RunConfig
 
 
@@ -33,6 +34,7 @@ def main(argv=None):
     ap.add_argument("--preempt-at", type=int, default=None)
     args = ap.parse_args(argv)
 
+    enable_persistent_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="repro_train_")
     run = RunConfig(steps=args.steps, batch=args.batch, seq=args.seq,

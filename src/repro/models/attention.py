@@ -3,8 +3,9 @@ sequences, plus single-token decode against a (ring-buffer) KV cache.
 
 The chunked path is the XLA-compileable analogue of the Pallas flash kernel
 in ``repro.kernels.flash_attention`` — O(chunk x kv) live memory, lax.scan
-over query blocks.  The Pallas kernel is used on real TPUs; this path is what
-the dry-run lowers (identical FLOPs, so roofline terms match).
+over query blocks.  It is the only attention path of training and prefill on
+every backend, TPU included: nothing under ``repro.models`` calls the flash
+kernel.
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@ store, and skip JIT on the accelerators").
 
 Two layers:
   * jax's persistent compilation cache (XLA executable serialization) —
-    enabled per-process against a shared directory;
+    turned on by the entry points through ``enable_persistent_cache``;
   * an in-process AOT registry keyed by (arch, shape, mesh, donation
     signature) holding `Lowered`/`Compiled` objects so repeated launches
     within one controller reuse executables.
@@ -13,22 +13,33 @@ benchmark (fig14) uses it to quantify the INIT-time saving of a warm cache.
 """
 from __future__ import annotations
 
+import os
 import pathlib
 import time
 from typing import Any, Callable, Dict, Hashable, Tuple
 
 import jax
 
-_CACHE_ENABLED = False
+# The directory is part of every cache key, so it must not move between
+# runs: a fixed path at the repo root, never a temporary name.
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable_persistent_cache(directory: str) -> None:
-    """Turn on XLA's on-disk executable cache (idempotent)."""
-    global _CACHE_ENABLED
+def enable_persistent_cache() -> str:
+    """Turn on XLA's on-disk executable cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache (jax reads it
+    itself and no other directory is set here); otherwise the cache is
+    ``DEFAULT_CACHE_DIR``.  Call before the first compile.  This is the
+    only place the repository sets ``jax_compilation_cache_dir``.
+    """
+    directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not directory:
+        directory = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", directory)
     pathlib.Path(directory).mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", directory)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _CACHE_ENABLED = True
+    return directory
 
 
 class CompileClock:

@@ -44,6 +44,7 @@ class RunConfig:
     async_restore: bool = True
     job_id: str = "job0"
     chips: int = 1
+    seed: int = 0                           # synthetic-data seed
 
     def __post_init__(self):
         if self.failure_kind not in ("preemption", "hardware"):
@@ -142,7 +143,7 @@ class Orchestrator:
         start_step = ckpt_step + 1 if restored is not None else 0
         self.state = restored if restored is not None else example
         pipeline = DataPipeline(self.cfg.vocab_size, r.batch, r.seq,
-                                seed=start_step).start()
+                                seed=r.seed + start_step).start()
         t_init1 = time.monotonic()
         if compile_s > 0:
             self._emit(Phase.INIT, t_init0, t_compiled,
