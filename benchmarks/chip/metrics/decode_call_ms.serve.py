@@ -1,0 +1,8 @@
+"""Executor layer: mean host-clock cost the executor returns for its
+decode() calls in the window (ms)."""
+
+
+def read(run):
+    costs = [c.cost for c in run.calls
+             if c.kind == "decode" and c.start < run.seconds]
+    return 1e3 * sum(costs) / len(costs) if costs else None
