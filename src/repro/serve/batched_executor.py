@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.models import model, transformer
 from repro.serve.kv_cache import FLASH_ATTENTION_BLOCK_K, PagedKVCache
+from repro.serve.spans import span
 
 
 class JaxBatchedExecutor:
@@ -81,12 +82,16 @@ class JaxBatchedExecutor:
             cfg_dec = dataclasses.replace(
                 cfg, capacity_factor=max(cfg.capacity_factor,
                                          float(cfg.num_experts)))
-        self._prefill = jax.jit(
-            lambda p, b: transformer.prefill(p, b, cfg, max_len=max_len))
-        self._scatter = jax.jit(
-            lambda c, kp, vp, pg, off: transformer.scatter_prefill_pages(
-                c, cfg, kp, vp, pg, off),
-            donate_argnums=(1, 2))
+        # named, so the trace shows them as jit_prefill and
+        # jit_scatter_prefill_pages
+        def prefill(p, b):
+            return transformer.prefill(p, b, cfg, max_len=max_len)
+
+        def scatter_prefill_pages(c, kp, vp, pg, off):
+            return transformer.scatter_prefill_pages(c, cfg, kp, vp, pg, off)
+
+        self._prefill = jax.jit(prefill)
+        self._scatter = jax.jit(scatter_prefill_pages, donate_argnums=(1, 2))
 
         step = model.paged_decode_fn(cfg_dec, attn_impl=attn_impl,
                                      interpret=interpret)
@@ -119,48 +124,57 @@ class JaxBatchedExecutor:
 
     def prefill(self, reqs: Sequence) -> Tuple[List[int], float]:
         t0 = self.clock()
-        pend = []
-        for r in reqs:
-            row = self._free_rows.pop()
-            self.rows[r.rid] = row
-            logits, cache = self._prefill(self.params, self._batch1(r))
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
-            table = self.kv.block_table(r.rid)     # engine allocated first
-            s = int(np.asarray(r.prompt).shape[-1])
-            pos = np.arange(s)
-            page_ids = jnp.asarray(np.asarray(table, np.int32)
-                                   [pos // self.block_tokens])
-            offs = jnp.asarray((pos % self.block_tokens).astype(np.int32))
-            self._kp, self._vp = self._scatter(cache, self._kp, self._vp,
-                                               page_ids, offs)
-            self._len[row] = s
-            pend.append((r, row, tok))
-        if pend:
-            jax.block_until_ready([t for _, _, t in pend])
-        cost = max(0.0, self.clock() - t0)
-        toks = []
-        for r, row, tok in pend:
-            t = int(tok[0])
-            self._tok[row] = t
-            toks.append(t)
+        with span("serve.prefill"):
+            pend = []
+            for r in reqs:
+                with span("serve.prefill.request"):
+                    row = self._free_rows.pop()
+                    self.rows[r.rid] = row
+                    logits, cache = self._prefill(self.params,
+                                                  self._batch1(r))
+                    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+                    table = self.kv.block_table(r.rid)  # engine allocated
+                    s = int(np.asarray(r.prompt).shape[-1])
+                    pos = np.arange(s)
+                    page_ids = jnp.asarray(np.asarray(table, np.int32)
+                                           [pos // self.block_tokens])
+                    offs = jnp.asarray(
+                        (pos % self.block_tokens).astype(np.int32))
+                    self._kp, self._vp = self._scatter(
+                        cache, self._kp, self._vp, page_ids, offs)
+                    self._len[row] = s
+                    pend.append((r, row, tok))
+            with span("serve.prefill.sync"):
+                if pend:
+                    jax.block_until_ready([t for _, _, t in pend])
+                cost = max(0.0, self.clock() - t0)
+                toks = []
+                for r, row, tok in pend:
+                    t = int(tok[0])
+                    self._tok[row] = t
+                    toks.append(t)
         return toks, cost
 
     def decode(self, reqs: Sequence) -> Tuple[List[int], float]:
         t0 = self.clock()
-        # refresh the gather map from the allocator (the engine's
-        # append_token may have claimed fresh blocks since last step)
-        for r in reqs:
-            row = self.rows[r.rid]
-            self._len[row] = self.kv.seq_len(r.rid)
-            table = self.kv.block_table(r.rid)
-            self._tables[row, :len(table)] = table
-        tok, self._kp, self._vp = self._decode(
-            self.params, jnp.asarray(self._tok), jnp.asarray(self._len),
-            self._kp, self._vp, jnp.asarray(self._tables))
-        tok_np = np.asarray(jax.block_until_ready(tok))
-        cost = max(0.0, self.clock() - t0)
-        self._tok = tok_np.copy()
-        return [int(tok_np[self.rows[r.rid]]) for r in reqs], cost
+        with span("serve.decode"):
+            with span("serve.decode.prepare"):
+                # refresh the gather map from the allocator (the engine's
+                # append_token may have claimed fresh blocks since last step)
+                for r in reqs:
+                    row = self.rows[r.rid]
+                    self._len[row] = self.kv.seq_len(r.rid)
+                    table = self.kv.block_table(r.rid)
+                    self._tables[row, :len(table)] = table
+                args = (jnp.asarray(self._tok), jnp.asarray(self._len),
+                        self._kp, self._vp, jnp.asarray(self._tables))
+            with span("serve.decode.dispatch"):
+                tok, self._kp, self._vp = self._decode(self.params, *args)
+            with span("serve.decode.sync"):
+                tok_np = np.asarray(jax.block_until_ready(tok))
+                cost = max(0.0, self.clock() - t0)
+                self._tok = tok_np.copy()
+                return [int(tok_np[self.rows[r.rid]]) for r in reqs], cost
 
     def release(self, req) -> None:
         row = self.rows.pop(req.rid, None)

@@ -51,6 +51,7 @@ from repro.core.attribution import _SHIFT, _exact
 from repro.core.goodput import Layer, Phase
 from repro.core.ledger import GoodputLedger
 from repro.serve.kv_cache import OutOfBlocksError, PagedKVCache
+from repro.serve.spans import span
 
 try:
     import numpy as _np
@@ -347,21 +348,22 @@ class ContinuousServeEngine:
         while queue or active:
             # 1) admission: drain arrived requests into free slots, gated
             #    on the paged cache fitting their full sequence right now
-            admitted: List[ServeRequest] = []
-            while queue and len(active) + len(admitted) < self.n_slots:
-                nxt = queue[0]
-                if nxt.t_submit > self.t:
-                    if active or admitted:
-                        break
-                    # engine idle: jump to the next arrival
-                    self._advance(nxt.t_submit - self.t, busy=0)
-                    continue
-                if not kv.can_allocate(nxt.prompt_len + nxt.max_new):
-                    break             # wait for detaches to free blocks
-                queue.popleft()
-                kv.allocate(nxt.rid, nxt.prompt_len)
-                nxt.t_admit = self.t
-                admitted.append(nxt)
+            with span("serve.engine.admit"):
+                admitted: List[ServeRequest] = []
+                while queue and len(active) + len(admitted) < self.n_slots:
+                    nxt = queue[0]
+                    if nxt.t_submit > self.t:
+                        if active or admitted:
+                            break
+                        # engine idle: jump to the next arrival
+                        self._advance(nxt.t_submit - self.t, busy=0)
+                        continue
+                    if not kv.can_allocate(nxt.prompt_len + nxt.max_new):
+                        break         # wait for detaches to free blocks
+                    queue.popleft()
+                    kv.allocate(nxt.rid, nxt.prompt_len)
+                    nxt.t_admit = self.t
+                    admitted.append(nxt)
 
             # 2) prefill phase: one op for this iteration's admissions
             if admitted:
@@ -383,20 +385,22 @@ class ContinuousServeEngine:
 
             # 3) KV growth for this decode iteration; exhaustion preempts
             #    the youngest other request (recompute preemption)
-            survivors: List[ServeRequest] = []
-            for r in list(active):
-                if r not in active:
-                    continue          # preempted by an earlier grower
-                while True:
-                    try:
-                        kv.append_token(r.rid)
-                        survivors.append(r)
-                        break
-                    except OutOfBlocksError:
-                        victim = self._pick_victim(active, exclude=r)
-                        assert victim is not None, \
-                            "sole request cannot exhaust a validated cache"
-                        self._preempt(victim, active, survivors, queue)
+            with span("serve.engine.kv_grow"):
+                survivors: List[ServeRequest] = []
+                for r in list(active):
+                    if r not in active:
+                        continue      # preempted by an earlier grower
+                    while True:
+                        try:
+                            kv.append_token(r.rid)
+                            survivors.append(r)
+                            break
+                        except OutOfBlocksError:
+                            victim = self._pick_victim(active, exclude=r)
+                            assert victim is not None, \
+                                "sole request cannot exhaust a validated " \
+                                "cache"
+                            self._preempt(victim, active, survivors, queue)
 
             # 4) decode one iteration for the survivors
             toks, cost = self.executor.decode(survivors)
@@ -445,11 +449,12 @@ class ContinuousServeEngine:
         queue.appendleft(victim)
 
     def _detach(self, r: ServeRequest, done: List[ServeRequest]) -> None:
-        r.t_done = self.t
-        self.kv.free(r.rid)
-        self.executor.release(r)
-        self._flush_request(r)
-        done.append(r)
+        with span("serve.engine.detach"):
+            r.t_done = self.t
+            self.kv.free(r.rid)
+            self.executor.release(r)
+            self._flush_request(r)
+            done.append(r)
 
     def _report(self, done: List[ServeRequest], engine: str) -> ServeReport:
         span = max(0.0, self.t - self._t_start)
