@@ -113,26 +113,30 @@ def phase_kernel_vs_ref(cfg, seed: int) -> None:
     from repro.kernels.paged_attention.ops import (DEFAULT_BLOCK_TOKENS,
                                                    paged_attention_decode)
 
-    bt, nb = DEFAULT_BLOCK_TOKENS, 4
+    bt, nb, n_layers, layer = DEFAULT_BLOCK_TOKENS, 4, 2, 1
     n_pages = W * nb + 1
     # an inactive row, lengths off and on page edges, and a full table
     lengths = jnp.asarray([0, 1, 100, bt, bt + 1, 300, 383, nb * bt],
                           jnp.int32)
-    ks = jax.random.split(jax.random.key(seed), 3)
+    ks = jax.random.split(jax.random.key(seed), 5)
     q = jax.random.normal(ks[0], (W, cfg.num_heads, cfg.head_dim),
                           jnp.bfloat16)
-    pages = (cfg.num_kv_heads, n_pages, bt, cfg.head_dim)
-    kp = jax.random.normal(ks[1], pages, jnp.bfloat16)
-    vp = jax.random.normal(ks[2], pages, jnp.bfloat16)
+    new = (W, cfg.num_kv_heads, cfg.head_dim)
+    k = jax.random.normal(ks[1], new, jnp.bfloat16)
+    v = jax.random.normal(ks[2], new, jnp.bfloat16)
+    pages = (n_layers, cfg.num_kv_heads, n_pages, cfg.head_dim, bt)
+    kp = jax.random.normal(ks[3], pages, jnp.bfloat16)
+    vp = jax.random.normal(ks[4], pages, jnp.bfloat16)
     tables = jnp.asarray(np.random.default_rng(seed).permutation(n_pages)
                          [:W * nb].reshape(W, nb), jnp.int32)
     tol = ATTN_TOL_REL * float(jnp.max(jnp.abs(vp.astype(jnp.float32))))
     inactive = np.asarray(lengths) == 0
     for window in (0, 64):
-        out = {impl: np.asarray(paged_attention_decode(
-            q, kp, vp, tables, lengths, window=window, impl=impl,
-            interpret=False).astype(jnp.float32))
-            for impl in ("kernel", "ref")}
+        res = {impl: paged_attention_decode(
+            q, k, v, kp, vp, layer, tables, lengths, window=window,
+            impl=impl, interpret=False) for impl in ("kernel", "ref")}
+        out = {impl: np.asarray(r[0].astype(jnp.float32))
+               for impl, r in res.items()}
         diff = float(np.max(np.abs(out["kernel"] - out["ref"])))
         say(f"kernel vs ref attention (window={window}): max|diff|={diff} "
             f"tol={tol}")
@@ -140,6 +144,9 @@ def phase_kernel_vs_ref(cfg, seed: int) -> None:
             check(not np.any(o[inactive]),
                   f"{impl}: inactive rows are not exactly zero")
         check(diff <= tol, f"attention kernel vs ref {diff} > {tol}")
+        check(all(bool(jnp.array_equal(a, b)) for a, b in
+                  zip(res["kernel"][1:], res["ref"][1:])),
+              "kernel and ref wrote the pools differently")
 
 
 def phase_serve(cfg, seed: int) -> None:

@@ -34,20 +34,23 @@ def resolve_impl(impl: str = "auto") -> str:
 
 
 @functools.partial(jax.jit, static_argnames=("window", "impl", "interpret"))
-def paged_attention_decode(q, k_pages, v_pages, block_tables, lengths, *,
-                           window: int = 0, impl: str = "auto",
+def paged_attention_decode(q, k, v, k_pages, v_pages, layer, block_tables,
+                           lengths, *, window: int = 0, impl: str = "auto",
                            interpret: bool = False):
-    """One decode step of paged attention; see the kernel docstring.
+    """One decode step of paged attention, writing the step's K/V into
+    the pool first; see the kernel docstring.
 
-    q: (b, hq, d); k_pages/v_pages: (hkv, n_pages, block_tokens, d);
+    q: (b, hq, d); k/v: (b, hkv, d); k_pages/v_pages:
+    (n_layers, hkv, n_pages, d, block_tokens); layer: int32 scalar;
     block_tables: (b, nb) int32; lengths: (b,) int32.  Returns
-    (b, hq, d).
+    (out (b, hq, d), k_pages, v_pages).
     """
     impl = resolve_impl(impl)
     if impl == "kernel":
-        return paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                               window=window, interpret=interpret)
+        return paged_attention(q, k, v, k_pages, v_pages, layer,
+                               block_tables, lengths, window=window,
+                               interpret=interpret)
     if impl == "ref":
-        return paged_attention_ref(q, k_pages, v_pages, block_tables,
-                                   lengths, window=window)
+        return paged_attention_ref(q, k, v, k_pages, v_pages, layer,
+                                   block_tables, lengths, window=window)
     raise ValueError(f"unknown paged-attention impl: {impl!r}")

@@ -395,9 +395,10 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int,
 
 def paged_kv_shape(cfg: ModelConfig, n_pages: int, block_tokens: int):
     """Page-pool tensor shape for one replica: every layer's KV lives in
-    one stacked pool so a single block table addresses all layers."""
-    return (cfg.num_layers, cfg.num_kv_heads, n_pages, block_tokens,
-            cfg.head_dim)
+    one stacked pool so a single block table addresses all layers; each
+    page is a (head_dim, block_tokens) tile, tokens in the lanes."""
+    return (cfg.num_layers, cfg.num_kv_heads, n_pages, cfg.head_dim,
+            block_tokens)
 
 
 def _full_stack_kv(cache, cfg: ModelConfig):
@@ -434,10 +435,12 @@ def scatter_prefill_pages(cache, cfg: ModelConfig, k_pages, v_pages,
     """
     k_st, v_st = _full_stack_kv(cache, cfg)     # (L, 1, S, hkv, hd)
     s = page_ids.shape[0]
-    kv_k = k_st[:, 0, :s].transpose(0, 2, 1, 3)  # (L, hkv, s, hd)
-    kv_v = v_st[:, 0, :s].transpose(0, 2, 1, 3)
-    k_pages = k_pages.at[:, :, page_ids, offs].set(kv_k.astype(k_pages.dtype))
-    v_pages = v_pages.at[:, :, page_ids, offs].set(kv_v.astype(v_pages.dtype))
+    kv_k = k_st[:, 0, :s].transpose(1, 0, 2, 3)  # (s, L, hkv, hd)
+    kv_v = v_st[:, 0, :s].transpose(1, 0, 2, 3)
+    k_pages = k_pages.at[:, :, page_ids, :, offs].set(
+        kv_k.astype(k_pages.dtype))
+    v_pages = v_pages.at[:, :, page_ids, :, offs].set(
+        kv_v.astype(v_pages.dtype))
     return k_pages, v_pages
 
 
@@ -450,22 +453,18 @@ def paged_decode_step(params, token, lengths, k_pages, v_pages, block_tables,
     valid positions per row *including* the token written this step
     (the engine's ``append_token`` runs first), so the new KV is written
     at absolute position ``lengths - 1`` and attention spans ``lengths``
-    positions.  ``lengths == 0`` marks an inactive batch row: its writes
-    land in whatever (null) page its all-null block-table row names, and
-    its logits are garbage the caller must mask.  Fixed shapes in, fixed
-    shapes out — admission/detach never recompiles.
+    positions.  ``lengths == 0`` marks an inactive batch row: it writes
+    nothing into the pool, and its logits are garbage the caller must
+    mask.  Fixed shapes in, fixed shapes out — admission/detach never
+    recompiles.  Each layer's paged-attention call writes its K/V rows
+    into the stacked pool in place and reads the layer by index: no
+    per-layer slice of the pool is ever taken.
 
     Returns (logits (b, V), k_pages, v_pages).
     """
     from repro.kernels.paged_attention.ops import paged_attention_decode
 
-    b = token.shape[0]
-    btok = k_pages.shape[3]
-    write_pos = jnp.maximum(lengths - 1, 0)
-    page_ids = jnp.take_along_axis(
-        block_tables, (write_pos // btok)[:, None], axis=1)[:, 0]
-    offs = write_pos % btok
-    positions = write_pos[:, None].astype(jnp.int32)
+    positions = jnp.maximum(lengths - 1, 0)[:, None].astype(jnp.int32)
     window = cfg.attention_window or 0
     use_rope = cfg.family != "encdec"
 
@@ -478,18 +477,9 @@ def paged_decode_step(params, token, lengths, k_pages, v_pages, block_tables,
         h = shard_activation(h, "act")
         hn = norm(h, bp, "ln1", cfg)
         q, k, v = project_qkv(hn, bp["attn"], cfg, positions, use_rope)
-        kpi = jax.lax.dynamic_index_in_dim(kp, li, 0, keepdims=False)
-        vpi = jax.lax.dynamic_index_in_dim(vp, li, 0, keepdims=False)
-        # (b, 1, hkv, hd) -> (hkv, b, hd): row r writes (page_ids[r], offs[r])
-        kpi = kpi.at[:, page_ids, offs].set(
-            k[:, 0].transpose(1, 0, 2).astype(kpi.dtype))
-        vpi = vpi.at[:, page_ids, offs].set(
-            v[:, 0].transpose(1, 0, 2).astype(vpi.dtype))
-        kp = jax.lax.dynamic_update_index_in_dim(kp, kpi, li, 0)
-        vp = jax.lax.dynamic_update_index_in_dim(vp, vpi, li, 0)
-        o = paged_attention_decode(q[:, 0], kpi, vpi, block_tables, lengths,
-                                   window=window, impl=attn_impl,
-                                   interpret=interpret)
+        o, kp, vp = paged_attention_decode(
+            q[:, 0], k[:, 0], v[:, 0], kp, vp, li, block_tables, lengths,
+            window=window, impl=attn_impl, interpret=interpret)
         return h + merge_heads_out(o[:, None], bp["attn"]), kp, vp
 
     for i in range(cfg.first_k_dense):

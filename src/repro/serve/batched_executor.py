@@ -16,7 +16,12 @@ live slot in ONE jitted call:
     on ``self.kv``; before each decode the executor re-reads the live
     tables and sequence lengths into its fixed (W, nb_max) host arrays,
     so allocator state IS the kernel's gather map (one extra *null* page
-    backs inactive rows' writes);
+    fills the tables of inactive rows, which read and write nothing);
+  * **one stacked pool** — K and V each live in one
+    ``(n_layers, hkv, n_pages, head_dim, block_tokens)`` array (see
+    ``transformer.paged_kv_shape``); each layer's paged-attention call
+    takes the whole pool and its layer index, and writes the step's K/V
+    into it in place;
   * **prefill reuse** — prompts run through the same batch-1 jitted
     prefill as the per-slot executor (bitwise-identical first token),
     then the collected cache scatters into this request's pages.

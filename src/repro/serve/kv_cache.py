@@ -10,10 +10,11 @@ worst-case ``prompt + max_new`` a dense per-slot cache must reserve.
 
 ``block_tokens`` defaults to 128 — the MXU-aligned ``block_k`` tile of
 the Pallas flash-attention kernel
-(``repro.kernels.flash_attention``): a paged attention kernel consumes
-the KV cache one (block_k, head_dim) VMEM tile per grid step, so sizing
-allocator blocks to the kernel's kv tile means a block table maps 1:1
-onto kernel grid iterations with no partial-tile waste.
+(``repro.kernels.flash_attention``): the paged attention kernel
+(``repro.kernels.paged_attention``) consumes the KV cache one
+(head_dim, block_k) VMEM page per grid step, tokens in the lanes, so
+sizing allocator blocks to the kernel's kv tile means a block table maps
+1:1 onto kernel grid iterations with no partial-tile waste.
 
 Everything is deterministic: the free list is a LIFO stack, so the same
 admission/free sequence always yields the same block tables (the serve

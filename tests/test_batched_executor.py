@@ -82,13 +82,17 @@ def test_paged_step_kernel_matches_ref_logits():
     tok = jnp.asarray(ex._tok)
     lens = jnp.asarray(ex._len)
     bt = jnp.asarray(ex._tables)
-    out = {}
+    out, pools = {}, {}
     for impl in ("ref", "kernel"):
         step = model.paged_decode_fn(cfg, attn_impl=impl, interpret=True)
-        logits, _, _ = step(ex.params, tok, lens, ex._kp, ex._vp, bt)
-        out[impl] = np.asarray(logits)
+        logits, kp, vp = step(ex.params, tok, lens, ex._kp, ex._vp, bt)
+        out[impl], pools[impl] = np.asarray(logits), (kp, vp)
     np.testing.assert_allclose(out["kernel"], out["ref"], atol=1e-4)
     assert np.array_equal(out["kernel"].argmax(-1), out["ref"].argmax(-1))
+    # both write the step's K/V into the same places of the pool
+    for k, r in zip(pools["kernel"], pools["ref"]):
+        np.testing.assert_allclose(np.asarray(k, np.float32),
+                                   np.asarray(r, np.float32), atol=1e-2)
 
 
 def test_make_executor_falls_back_for_unpaged_families():

@@ -1,6 +1,7 @@
 """Paged-attention decode kernel: equivalence against the pure-jnp gather
 reference AND the model's dense ``decode_attention``, across mixed
-lengths, GQA group sizes, and sliding windows; plus the block-size pin
+lengths, GQA group sizes, sliding windows and pool layers; the in-place
+write of the step's K/V into the stacked pool; plus the block-size pin
 that keeps allocator pages equal to kernel kv tiles."""
 import jax
 import jax.numpy as jnp
@@ -19,17 +20,38 @@ from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.serve.kv_cache import FLASH_ATTENTION_BLOCK_K, PagedKVCache
 
 
-def _mk(seed, b, hq, hkv, d, n_pages, bt, nb, lengths):
-    ks = jax.random.split(jax.random.key(seed), 3)
+def _mk(seed, b, hq, hkv, d, n_pages, bt, nb, lengths, n_layers=2):
+    """q, the step's new k/v, stacked (L, hkv, P, d, bt) pools, tables
+    and lengths."""
+    ks = jax.random.split(jax.random.key(seed), 5)
     q = jax.random.normal(ks[0], (b, hq, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (hkv, n_pages, bt, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (hkv, n_pages, bt, d), jnp.float32)
+    k = jax.random.normal(ks[1], (b, hkv, d), jnp.float32)
+    v = jax.random.normal(ks[2], (b, hkv, d), jnp.float32)
+    pages = (n_layers, hkv, n_pages, d, bt)
+    kp = jax.random.normal(ks[3], pages, jnp.float32)
+    vp = jax.random.normal(ks[4], pages, jnp.float32)
     # distinct pages per row, shuffled so table order != page order
     rng = np.random.default_rng(seed)
     perm = rng.permutation(n_pages)[: b * nb].reshape(b, nb)
     bt_m = jnp.asarray(perm, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
-    return q, kp, vp, bt_m, lens
+    return q, k, v, kp, vp, bt_m, lens
+
+
+def _assert_same(got, want):
+    """(out, k_pages, v_pages) of two impls: outputs close, pools equal."""
+    np.testing.assert_allclose(got[0], want[0], atol=3e-6)
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(got[2], want[2])
+
+
+def _dense(pages, layer, bt_m):
+    """(L, hkv, P, d, bt) pool -> the layer's dense (b, S, hkv, d) cache:
+    table order is position order per the block-table ABI."""
+    b, nb = bt_m.shape
+    _, hkv, _, d, bt = pages.shape
+    return np.asarray(pages)[layer][:, np.asarray(bt_m)] \
+        .transpose(1, 2, 4, 0, 3).reshape(b, nb * bt, hkv, d)
 
 
 # ---------------------------------------------------------------------------
@@ -48,21 +70,83 @@ def test_paged_matches_ref(impl, b, hq, hkv, d, bt, nb, window):
     n_pages = b * nb + 1
     lengths = [(i * 7 + 3) % (nb * bt) + 1 for i in range(b)]
     lengths[0] = nb * bt             # one full row
-    q, kp, vp, bt_m, lens = _mk(b + d, b, hq, hkv, d, n_pages, bt, nb,
-                                lengths)
-    out = paged_attention_decode(q, kp, vp, bt_m, lens, window=window,
-                                 impl=impl, interpret=True)
-    ref = paged_attention_ref(q, kp, vp, bt_m, lens, window=window)
-    np.testing.assert_allclose(out, ref, atol=3e-6)
+    q, k, v, kp, vp, bt_m, lens = _mk(b + d, b, hq, hkv, d, n_pages, bt, nb,
+                                      lengths)
+    out = paged_attention_decode(q, k, v, kp, vp, 1, bt_m, lens,
+                                 window=window, impl=impl, interpret=True)
+    ref = paged_attention_ref(q, k, v, kp, vp, 1, bt_m, lens, window=window)
+    _assert_same(out, ref)
 
 
 def test_inactive_rows_output_exact_zeros():
-    q, kp, vp, bt_m, lens = _mk(1, 4, 4, 2, 16, 13, 8, 3, [0, 5, 0, 17])
+    q, k, v, kp, vp, bt_m, lens = _mk(1, 4, 4, 2, 16, 13, 8, 3,
+                                      [0, 5, 0, 17])
     for impl in ("kernel", "ref"):
-        out = paged_attention_decode(q, kp, vp, bt_m, lens, impl=impl,
-                                     interpret=True)
+        out, _, _ = paged_attention_decode(q, k, v, kp, vp, 0, bt_m, lens,
+                                           impl=impl, interpret=True)
         assert np.all(np.asarray(out)[[0, 2]] == 0.0), impl
         assert np.all(np.isfinite(np.asarray(out)))
+
+
+@pytest.mark.parametrize("impl", ["kernel", "ref"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_layer_index_picks_its_layer(impl, layer):
+    """The layer index alone chooses which layer of the stacked pool is
+    read and written: the same as handing the call that layer alone."""
+    q, k, v, kp, vp, bt_m, lens = _mk(3, 3, 4, 2, 16, 10, 8, 3,
+                                      [20, 9, 1], n_layers=3)
+    out, ko, vo = paged_attention_decode(q, k, v, kp, vp, layer, bt_m, lens,
+                                         impl=impl, interpret=True)
+    one = slice(layer, layer + 1)
+    alone = paged_attention_ref(q, k, v, kp[one], vp[one], 0, bt_m, lens)
+    np.testing.assert_allclose(out, alone[0], atol=3e-6)
+    np.testing.assert_array_equal(ko[one], alone[1])
+    np.testing.assert_array_equal(vo[one], alone[2])
+    for other in {0, 1, 2} - {layer}:
+        np.testing.assert_array_equal(ko[other], kp[other])
+        np.testing.assert_array_equal(vo[other], vp[other])
+        o_other, _, _ = paged_attention_ref(q, k, v, kp, vp, other, bt_m,
+                                            lens)
+        assert not np.allclose(out, o_other, atol=1e-3)
+
+
+def test_in_place_write_touches_only_the_new_columns():
+    """The kernel writes row r's new K/V at (layer, ih, its page, :, its
+    column) for active rows and nowhere else, and attends over it: the
+    stale column it overwrites is poisoned, so reading it would show."""
+    b, hq, hkv, d, bt, nb, layer = 4, 4, 2, 16, 8, 3, 1
+    lengths = [17, 0, 8, 1]              # mid-page, inactive, page end, first
+    q, k, v, kp, vp, bt_m, lens = _mk(11, b, hq, hkv, d, b * nb + 1, bt, nb,
+                                      lengths)
+    tables, lens_np = np.asarray(bt_m), np.asarray(lens)
+    active = [r for r in range(b) if lens_np[r] > 0]
+    page = {r: tables[r, (lens_np[r] - 1) // bt] for r in active}
+    off = {r: (lens_np[r] - 1) % bt for r in active}
+    for r in active:
+        kp = kp.at[layer, :, page[r], :, off[r]].set(1e4)
+        vp = vp.at[layer, :, page[r], :, off[r]].set(1e4)
+
+    out, ko, vo = paged_attention_decode(q, k, v, kp, vp, layer, bt_m, lens,
+                                         impl="kernel", interpret=True)
+    _assert_same((out, ko, vo),
+                 paged_attention_ref(q, k, v, kp, vp, layer, bt_m, lens))
+
+    want_k, want_v = np.array(kp), np.array(vp)
+    for r in active:
+        want_k[layer, :, page[r], :, off[r]] = np.asarray(k)[r]
+        want_v[layer, :, page[r], :, off[r]] = np.asarray(v)[r]
+    np.testing.assert_array_equal(ko, want_k)
+    np.testing.assert_array_equal(vo, want_v)
+    # the inactive row's pages are untouched
+    assert np.array_equal(np.asarray(ko)[:, :, tables[1]],
+                          np.asarray(kp)[:, :, tables[1]])
+
+    from repro.models.attention import decode_attention
+    dense = decode_attention(
+        q[:, None], jnp.asarray(_dense(want_k, layer, bt_m)),
+        jnp.asarray(_dense(want_v, layer, bt_m)), lens)[:, 0]
+    np.testing.assert_allclose(np.asarray(out)[active],
+                               np.asarray(dense)[active], atol=3e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -75,17 +159,12 @@ def test_paged_matches_dense_decode_attention(impl):
 
     b, hq, hkv, d, bt, nb = 3, 4, 2, 16, 8, 3
     lengths = [24, 9, 1]
-    q, kp, vp, bt_m, lens = _mk(5, b, hq, hkv, d, b * nb, bt, nb, lengths)
-    out = paged_attention_decode(q, kp, vp, bt_m, lens, impl=impl,
-                                 interpret=True)
-    # gather the pages back into the dense (b, S, hkv, d) cache layout:
-    # table order is position order per the block-table ABI
-    k_dense = np.asarray(kp)[:, np.asarray(bt_m)].transpose(1, 0, 2, 3, 4) \
-        .reshape(b, hkv, nb * bt, d).transpose(0, 2, 1, 3)
-    v_dense = np.asarray(vp)[:, np.asarray(bt_m)].transpose(1, 0, 2, 3, 4) \
-        .reshape(b, hkv, nb * bt, d).transpose(0, 2, 1, 3)
-    dense = decode_attention(q[:, None], jnp.asarray(k_dense),
-                             jnp.asarray(v_dense), lens)[:, 0]
+    q, k, v, kp, vp, bt_m, lens = _mk(5, b, hq, hkv, d, b * nb, bt, nb,
+                                      lengths)
+    out, ko, vo = paged_attention_decode(q, k, v, kp, vp, 1, bt_m, lens,
+                                         impl=impl, interpret=True)
+    dense = decode_attention(q[:, None], jnp.asarray(_dense(ko, 1, bt_m)),
+                             jnp.asarray(_dense(vo, 1, bt_m)), lens)[:, 0]
     np.testing.assert_allclose(out, dense, atol=3e-6)
 
 
@@ -101,12 +180,13 @@ def test_paged_attention_property(seed, g, window, bt):
     b, hkv, d, nb = 4, 2, 16, 2
     hq = g * hkv
     lengths = rng.integers(0, nb * bt + 1, b).tolist()
-    q, kp, vp, bt_m, lens = _mk(seed, b, hq, hkv, d, b * nb + 1, bt, nb,
-                                lengths)
-    out = paged_attention_decode(q, kp, vp, bt_m, lens, window=window,
-                                 impl="kernel", interpret=True)
-    ref = paged_attention_ref(q, kp, vp, bt_m, lens, window=window)
-    np.testing.assert_allclose(out, ref, atol=3e-6)
+    q, k, v, kp, vp, bt_m, lens = _mk(seed, b, hq, hkv, d, b * nb + 1, bt,
+                                      nb, lengths)
+    out = paged_attention_decode(q, k, v, kp, vp, seed % 2, bt_m, lens,
+                                 window=window, impl="kernel", interpret=True)
+    ref = paged_attention_ref(q, k, v, kp, vp, seed % 2, bt_m, lens,
+                              window=window)
+    _assert_same(out, ref)
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,9 @@ compiled HLO for the kernel (``tpu_custom_call``).  All of them live in
 this one file, so under several pytest workers only the worker that is
 given it loads the TPU library.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -62,12 +65,13 @@ def test_paged_kernel_compiles_at_smollm_widths(one_chip, no_persistent_cache,
                                                  window):
     cfg = get_config("smollm-135m")
     hkv, d = cfg.num_kv_heads, cfg.head_dim
-    n_pages = W * NB + 1
+    pages = transformer.paged_kv_shape(cfg, W * NB + 1, DEFAULT_BLOCK_TOKENS)
     args = (_spec((W, cfg.num_heads, d), jnp.bfloat16, one_chip),
-            _spec((hkv, n_pages, DEFAULT_BLOCK_TOKENS, d), jnp.bfloat16,
-                  one_chip),
-            _spec((hkv, n_pages, DEFAULT_BLOCK_TOKENS, d), jnp.bfloat16,
-                  one_chip),
+            _spec((W, hkv, d), jnp.bfloat16, one_chip),
+            _spec((W, hkv, d), jnp.bfloat16, one_chip),
+            _spec(pages, jnp.bfloat16, one_chip),
+            _spec(pages, jnp.bfloat16, one_chip),
+            _spec((), jnp.int32, one_chip),
             _spec((W, NB), jnp.int32, one_chip),
             _spec((W,), jnp.int32, one_chip))
     hlo = paged_attention_decode.lower(
@@ -76,18 +80,64 @@ def test_paged_kernel_compiles_at_smollm_widths(one_chip, no_persistent_cache,
     assert "tpu_custom_call" in hlo
 
 
-def test_full_width_paged_decode_step_compiles(one_chip, no_persistent_cache):
-    cfg = get_config("smollm-135m")
+def _paged_step(cfg, one_chip, width, pages_per_row):
+    """The serving executor's decode program, compiled for the chip at
+    ``width`` rows of ``pages_per_row`` pages, pools donated."""
     params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip),
                           abstract_params(cfg))
-    pages = transformer.paged_kv_shape(cfg, W * NB + 1, DEFAULT_BLOCK_TOKENS)
+    pages = transformer.paged_kv_shape(cfg, width * pages_per_row + 1,
+                                       DEFAULT_BLOCK_TOKENS)
     args = (params,
-            _spec((W,), jnp.int32, one_chip),
-            _spec((W,), jnp.int32, one_chip),
+            _spec((width,), jnp.int32, one_chip),
+            _spec((width,), jnp.int32, one_chip),
             _spec(pages, cfg.compute_dtype, one_chip),
             _spec(pages, cfg.compute_dtype, one_chip),
-            _spec((W, NB), jnp.int32, one_chip))
+            _spec((width, pages_per_row), jnp.int32, one_chip))
     step = jax.jit(model.paged_decode_fn(cfg, attn_impl="kernel"),
                    donate_argnums=(3, 4))
-    hlo = step.lower(*args).compile().as_text()
+    return pages, step.lower(*args).compile().as_text()
+
+
+def test_full_width_paged_decode_step_compiles(one_chip, no_persistent_cache):
+    _, hlo = _paged_step(get_config("smollm-135m"), one_chip, W, NB)
     assert "tpu_custom_call" in hlo
+
+
+# ops that only name or pass on an array, never move its bytes
+_PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                 "while", "custom-call"}
+
+
+def test_decode_step_leaves_the_kv_pool_in_place(one_chip,
+                                                 no_persistent_cache):
+    """At the benchmark cell's shapes (128 rows of 16 pages, 2049 pages)
+    no XLA op copies, slices, scatters into or relayouts the stacked KV
+    pool or one layer of it: only the paged-attention kernel touches the
+    pool, which it reads and writes in place, and both pools alias the
+    donated inputs."""
+    cfg = get_config("smollm-135m")
+    pages, hlo = _paged_step(cfg, one_chip, 128, 16)
+    pool = math.prod(pages)
+    layer = pool // pages[0]
+    moved = []
+    params = {}                 # the entry computation's, by number
+    entry = hlo.index("\nENTRY ")
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* "
+                     r"([\w-]+)\((.*)", line)
+        if not m:
+            continue
+        name, dims, op = m.group(1), m.group(3), m.group(4)
+        size = math.prod(int(x) for x in dims.split(",") if x)
+        if op == "parameter" and hlo.index(line) > entry:
+            params[int(re.match(r"(\d+)\)", m.group(5)).group(1))] = size
+        if size in (pool, layer) and (
+                op not in _PASS_THROUGH
+                or (op == "custom-call" and "tpu_custom_call" not in line)):
+            moved.append(f"{op} {name}")
+    assert not moved, moved
+    aliases = re.search(r"input_output_alias=\{ (.*?) \}", hlo)
+    assert aliases, "the pools alias no input"
+    pairs = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases.group(1)))
+    assert set(pairs) == {"1", "2"}, pairs
+    assert all(params[int(p)] == pool for p in pairs.values()), pairs
