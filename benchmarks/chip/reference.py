@@ -1,20 +1,19 @@
-"""The plain reference: a float32 dense decoder in straightforward
-``jax.numpy``, shared by every dense configuration, with no kernel, cache,
-paging or batching of requests.
+"""The plain reference: a float32 decoder in straightforward ``jax.numpy``,
+with no kernel, cache, paging or batching of requests.  What is shared by
+every family is here: the matrix product, RMSNorm, rotary position
+embedding, the layer-by-layer pass and the served tokens' logit gaps.  Each
+family's decoder layer is its ``block`` (``family.py``).
 
-RMSNorm, rotary position embedding (half-split rotation, as HF Llama and
-Qwen2), grouped-query attention with a causal mask and a full softmax,
-optional biases on Q/K/V, a SwiGLU MLP, and a tied or untied head.  Every
-matrix product runs at ``Precision.HIGHEST``.  It imports nothing of the
-program: the weights come from ``weights`` and the seed, and the sizes
+Every matrix product runs at ``Precision.HIGHEST``.  It imports nothing of
+the program: the weights come from ``weights`` and the seed, and the sizes
 from the configuration's file.
 
 It runs layer by layer (each layer's weights are made, used and dropped)
 over blocks of rows, so that it fits next to nothing else on one chip.
 
-``quant="fp8"`` is the control: every operand of every matrix product is
-rounded to float8 e4m3 with a scale per row (activations) or per output
-column (weights), the step below the bfloat16 the configuration serves in.
+``quant`` is the control: every operand of every matrix product is rounded
+to float8 e4m3 with a scale per row (activations) or per output column
+(weights), the step below the bfloat16 the configuration serves in.
 """
 from __future__ import annotations
 
@@ -26,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import family
 import weights as W
 
 HI = jax.lax.Precision.HIGHEST
@@ -59,41 +59,10 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def block(x, w: Dict[str, jax.Array], c: Dict, quant: bool):
-    """One decoder layer over x: (b, s, d) float32."""
-    b, s, _ = x.shape
-    hq, hkv, hd = c["num_heads"], c["num_kv_heads"], c["head_dim"]
-    pos = jnp.arange(s)
-    h = _rmsnorm(x, w["ln1"], c["norm_eps"])
-    q = _mm(h, w["attn.wq"], quant)
-    k = _mm(h, w["attn.wk"], quant)
-    v = _mm(h, w["attn.wv"], quant)
-    if "attn.bq" in w:
-        q, k, v = q + w["attn.bq"], k + w["attn.bk"], v + w["attn.bv"]
-    q = _rope(q.reshape(b, s, hq, hd), pos, c["rope_theta"])
-    k = _rope(k.reshape(b, s, hkv, hd), pos, c["rope_theta"])
-    v = v.reshape(b, s, hkv, hd)
-    k = jnp.repeat(k, hq // hkv, axis=2)
-    v = jnp.repeat(v, hq // hkv, axis=2)
-    if quant:
-        q, k, v = _q8(q, -1), _q8(k, -1), _q8(v, 1)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HI) * hd ** -0.5
-    causal = pos[None, :] <= pos[:, None]
-    scores = jnp.where(causal, scores, -jnp.inf)
-    p = jax.nn.softmax(scores, axis=-1)
-    if quant:
-        p = _q8(p, -1)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=HI).reshape(b, s, -1)
-    x = x + _mm(o, w["attn.wo"], quant)
-    h = _rmsnorm(x, w["ln2"], c["norm_eps"])
-    ff = jax.nn.silu(_mm(h, w["mlp.wg"], quant)) * _mm(h, w["mlp.wi"], quant)
-    return x + _mm(ff, w["mlp.wo"], quant)
-
-
 @functools.lru_cache(maxsize=None)
-def _block_fn(cj: str, quant: bool):
+def _block_fn(fam, cj: str, quant: bool):
     c = json.loads(cj)
-    return jax.jit(lambda x, w: block(x, w, c, quant))
+    return jax.jit(lambda x, w: fam.block(x, w, c, quant))
 
 
 def _head_w(c: Dict, seed: int, dtype) -> jax.Array:
@@ -107,7 +76,7 @@ def hidden(c: Dict, seed: int, dtype, tokens: np.ndarray,
     """Final-norm hidden states (n, s, d) of the token rows, once for each
     entry of ``quants`` (False: the float32 reference; True: the fp8
     control), layer by layer over blocks of ``rows`` rows."""
-    cj = W._frozen(c)
+    fam, cj = family.of(c), W._frozen(c)
     n, s = tokens.shape
     pad = -n % rows
     toks = np.concatenate([tokens, np.zeros((pad, s), tokens.dtype)])
@@ -118,7 +87,7 @@ def hidden(c: Dict, seed: int, dtype, tokens: np.ndarray,
     for i in range(c["num_layers"]):
         w = W.layer(c, seed, i, dtype)
         for j, quant in enumerate(quants):
-            f = _block_fn(cj, bool(quant))
+            f = _block_fn(fam, cj, bool(quant))
             xs[j] = jnp.concatenate(
                 [f(xs[j][r:r + rows], w) for r in range(0, n + pad, rows)])
         del w

@@ -25,6 +25,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 import clock
+import family
 import reference
 import traffic
 import xplane
@@ -66,6 +67,7 @@ def model_config(cfile: Dict, role: str, smoke: bool):
     fields = dict(cfile["model"])
     if smoke:
         fields.update(cfile["smoke"])
+    family.of(fields)          # a family with no module fails here
     cfg = ModelConfig(
         **{k: v for k, v in fields.items() if k != "compute_dtype"},
         compute_dtype=jnp.dtype(fields["compute_dtype"]),
@@ -107,7 +109,7 @@ def sample(done: List, seed: int) -> List:
 
 
 def reference_rows(fields: Dict, pad_to: int) -> int:
-    per_row = fields["num_heads"] * pad_to * pad_to * 4 * 3
+    per_row = family.of(fields).reference_row_bytes(fields, pad_to)
     return int(max(1, min(SAMPLE_ROWS, ATTN_BUDGET // per_row)))
 
 

@@ -1,16 +1,17 @@
-"""Seeded weights of a dense decoder, made by the benchmark and not by the
-program, so that the plain reference can make the very same values again
-from the seed without taking anything the program holds.
+"""Seeded weights, made by the benchmark and not by the program, so that
+the plain reference can make the very same values again from the seed
+without taking anything the program holds.
 
-The layout is the program's dense-decoder parameter tree (``embed.tok``,
-``blocks.*`` stacked over layers, ``final_norm``, ``lm_head`` when the head
-is untied); the harness checks it against the program's own abstract
-parameters before it hands the weights over.  Matrices take the served
-dtype, vectors stay float32, as the program keeps them.
+The layout is the configuration's family's (``family.py``): the program's
+parameter tree, which the harness checks against the program's own
+abstract parameters before it hands the weights over.  A leaf takes the
+served dtype or float32 as its family says, and may be stacked over a
+count of layers on a leading axis.
 
-Every leaf, and every layer of a stacked leaf, has its own key folded from
-the seed, so one layer can be made alone (the reference's layer-by-layer
-pass) and equals that layer of the whole tree (one jitted call).
+Every leaf, and every entry of a stacked leaf, has its own key folded from
+the seed, the leaf's dotted name and its index in the stack, so one layer
+can be made alone (the reference's layer-by-layer pass) and equals that
+layer of the whole tree (one jitted call).
 """
 from __future__ import annotations
 
@@ -18,44 +19,13 @@ import functools
 import json
 import math
 import zlib
-from typing import Dict, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-# name -> (shape of one layer, stored in the served dtype, init, stacked)
-Leaf = Tuple[Tuple[int, ...], bool, str, bool]
-
-
-def layout(c: Dict) -> Dict[str, Leaf]:
-    """Flat ``{dotted.name: (shape, served_dtype, init, stacked)}``.  As in
-    the program, a leaf of two or more dimensions as stored (a matrix, or
-    a vector stacked over layers) takes the served dtype."""
-    d, ff, V = c["d_model"], c["d_ff"], c["vocab_size"]
-    q = c["num_heads"] * c["head_dim"]
-    kv = c["num_kv_heads"] * c["head_dim"]
-    leaves = [
-        ("embed.tok", (V, d), "embed", False),
-        ("final_norm", (d,), "norm", False),
-        ("blocks.ln1", (d,), "norm", True),
-        ("blocks.ln2", (d,), "norm", True),
-        ("blocks.attn.wq", (d, q), "fan_in", True),
-        ("blocks.attn.wk", (d, kv), "fan_in", True),
-        ("blocks.attn.wv", (d, kv), "fan_in", True),
-        ("blocks.attn.wo", (q, d), "fan_in", True),
-        ("blocks.mlp.wi", (d, ff), "fan_in", True),
-        ("blocks.mlp.wg", (d, ff), "fan_in", True),
-        ("blocks.mlp.wo", (ff, d), "fan_in", True),
-    ]
-    if c.get("qkv_bias"):
-        leaves += [("blocks.attn.bq", (q,), "bias", True),
-                   ("blocks.attn.bk", (kv,), "bias", True),
-                   ("blocks.attn.bv", (kv,), "bias", True)]
-    if not c.get("tie_embeddings"):
-        leaves.append(("lm_head", (d, V), "fan_in", False))
-    return {name: (shape, stacked or len(shape) >= 2, init, stacked)
-            for name, shape, init, stacked in leaves}
+import family
 
 
 def base_key(seed: int) -> jax.Array:
@@ -95,20 +65,19 @@ def _frozen(c: Dict) -> str:
 
 
 @functools.lru_cache(maxsize=None)
-def _make_fn(cj: str, dtype_name: str):
+def _make_fn(fam, cj: str, dtype_name: str):
     c = json.loads(cj)
     dt = jnp.dtype(dtype_name)
-    L = c["num_layers"]
 
     def make(key):
         flat = {}
-        for name, (shape, is_mat, init, stacked) in layout(c).items():
+        for name, (shape, served, init, stack) in fam.layout(c).items():
             k = _leaf_key(key, name)
-            ldt = dt if is_mat else jnp.float32
-            if stacked:
+            ldt = dt if served else jnp.float32
+            if stack:
                 flat[name] = jax.vmap(
                     lambda i: _value(jax.random.fold_in(k, i), shape, ldt,
-                                     init))(jnp.arange(L))
+                                     init))(jnp.arange(stack))
             else:
                 flat[name] = _value(k, shape, ldt, init)
         return _nest(flat)
@@ -118,45 +87,54 @@ def _make_fn(cj: str, dtype_name: str):
 
 def make(c: Dict, seed: int, dtype) -> Dict:
     """The whole parameter tree, in one jitted call on the default device."""
-    return _make_fn(_frozen(c), jnp.dtype(dtype).name)(base_key(seed))
+    return _make_fn(family.of(c), _frozen(c),
+                    jnp.dtype(dtype).name)(base_key(seed))
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fn(cj: str, dtype_name: str):
+def _layer_fn(fam, cj: str, dtype_name: str, names: tuple):
     c = json.loads(cj)
     dt = jnp.dtype(dtype_name)
+    leaves = fam.layout(c)
 
-    def one(key, i):
+    def one(key, index):
         out = {}
-        for name, (shape, is_mat, init, stacked) in layout(c).items():
-            if not stacked:
-                continue
-            k = jax.random.fold_in(_leaf_key(key, name), i)
-            v = _value(k, shape, dt if is_mat else jnp.float32, init)
-            out[name[len("blocks."):]] = v.astype(jnp.float32)
+        for j, (local, name) in enumerate(names):
+            shape, served, init, stack = leaves[name]
+            k = _leaf_key(key, name)
+            if stack:
+                k = jax.random.fold_in(k, index[j])
+            v = _value(k, shape, dt if served else jnp.float32, init)
+            out[local] = v.astype(jnp.float32)
         return out
 
     return jax.jit(one)
 
 
 def layer(c: Dict, seed: int, i: int, dtype) -> Dict[str, jax.Array]:
-    """Layer ``i``'s leaves as float32, named without ``blocks.``."""
-    return _layer_fn(_frozen(c), jnp.dtype(dtype).name)(
-        base_key(seed), jnp.int32(i))
+    """Decoder layer ``i``'s leaves as float32, named as the family's
+    ``layer_leaves`` names them."""
+    fam = family.of(c)
+    parts = fam.layer_leaves(c, i)
+    names = tuple((local, name) for local, (name, _) in parts.items())
+    index = jnp.asarray([-1 if j is None else j for _, j in parts.values()],
+                        jnp.int32)
+    return _layer_fn(fam, _frozen(c), jnp.dtype(dtype).name, names)(
+        base_key(seed), index)
 
 
 @functools.lru_cache(maxsize=None)
-def _top_fn(cj: str, dtype_name: str, name: str):
-    c = json.loads(cj)
-    shape, is_mat, init, _ = layout(c)[name]
-    dt = jnp.dtype(dtype_name) if is_mat else jnp.float32
+def _top_fn(fam, cj: str, dtype_name: str, name: str):
+    shape, served, init, _ = fam.layout(json.loads(cj))[name]
+    dt = jnp.dtype(dtype_name) if served else jnp.float32
     return jax.jit(lambda key: _value(_leaf_key(key, name), shape, dt, init))
 
 
 def top(c: Dict, seed: int, name: str, dtype) -> jax.Array:
     """One unstacked leaf (``embed.tok``, ``final_norm``, ``lm_head``) in
     its stored dtype."""
-    return _top_fn(_frozen(c), jnp.dtype(dtype).name, name)(base_key(seed))
+    return _top_fn(family.of(c), _frozen(c), jnp.dtype(dtype).name,
+                   name)(base_key(seed))
 
 
 def check_layout(c: Dict, dtype, abstract) -> None:
@@ -164,10 +142,9 @@ def check_layout(c: Dict, dtype, abstract) -> None:
     layout's names, shapes and dtypes."""
     dt = jnp.dtype(dtype)
     want = {}
-    L = c["num_layers"]
-    for name, (shape, is_mat, _, stacked) in layout(c).items():
-        want[name] = ((L,) + shape if stacked else shape,
-                      dt if is_mat else jnp.dtype(jnp.float32))
+    for name, (shape, served, _, stack) in family.of(c).layout(c).items():
+        want[name] = ((stack,) + shape if stack else shape,
+                      dt if served else jnp.dtype(jnp.float32))
     got = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(abstract)[0]:
         name = ".".join(str(getattr(p, "key", p)) for p in path)

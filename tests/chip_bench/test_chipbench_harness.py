@@ -13,7 +13,9 @@ import time
 
 import jax
 import jax.numpy as jnp
+import pytest
 
+import family
 import run
 import spec
 import traffic
@@ -103,6 +105,39 @@ def test_serve_sound_run_is_correct_and_faults_are_not(monkeypatch):
     # the fp8 control in the program's place is not correct either
     res = checks_of(ctx_for(SERVE, control=True), limit, monkeypatch)
     assert not res["correct"], res["checks"]
+
+
+def test_a_family_with_no_module_fails_at_set_up():
+    ctx = ctx_for(SERVE)
+    ctx.cfile["model"]["family"] = "no-such-family"
+    with pytest.raises(FileNotFoundError,
+                       match=r"families/no-such-family\.py"):
+        run.run_cell(ctx)
+
+
+# appended to a copy of families/dense.py: its reference layer, off by a
+# constant
+OFF_BY_A_CONSTANT = """
+
+_exact_block = block
+
+
+def block(x, w, c, quant):
+    return _exact_block(x, w, c, quant) + 1.0
+"""
+
+
+def test_the_check_reads_the_reference_layer_from_the_family_file(
+        monkeypatch, tmp_path):
+    sound = checks_of(ctx_for(SERVE), {}, monkeypatch)
+    gap = sound["checks"]["served_logit_gap"]["value"]
+    limit = {"served_logit_gap": {"limit": max(4 * gap, 0.02)}}
+    (tmp_path / "dense.py").write_text(
+        (family.DIR / "dense.py").read_text() + OFF_BY_A_CONSTANT)
+    monkeypatch.setattr(family, "DIR", tmp_path)
+    off = checks_of(ctx_for(SERVE), limit, monkeypatch)
+    assert (off["attempted"], off["failed"]) == (sound["attempted"], 0)
+    assert not off["correct"], off["checks"]
 
 
 def test_run_refuses_a_host_without_a_tpu(tmp_path):
