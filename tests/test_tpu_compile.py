@@ -7,6 +7,7 @@ compiled HLO for the kernel (``tpu_custom_call``).  All of them live in
 this one file, so under several pytest workers only the worker that is
 given it loads the TPU library.
 """
+import dataclasses
 import math
 import re
 
@@ -95,12 +96,12 @@ def _paged_step(cfg, one_chip, width, pages_per_row):
             _spec((width, pages_per_row), jnp.int32, one_chip))
     step = jax.jit(model.paged_decode_fn(cfg, attn_impl="kernel"),
                    donate_argnums=(3, 4))
-    return pages, step.lower(*args).compile().as_text()
+    return pages, step.lower(*args).compile()
 
 
 def test_full_width_paged_decode_step_compiles(one_chip, no_persistent_cache):
-    _, hlo = _paged_step(get_config("smollm-135m"), one_chip, W, NB)
-    assert "tpu_custom_call" in hlo
+    _, compiled = _paged_step(get_config("smollm-135m"), one_chip, W, NB)
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 # ops that only name or pass on an array, never move its bytes
@@ -116,7 +117,8 @@ def test_decode_step_leaves_the_kv_pool_in_place(one_chip,
     pool, which it reads and writes in place, and both pools alias the
     donated inputs."""
     cfg = get_config("smollm-135m")
-    pages, hlo = _paged_step(cfg, one_chip, 128, 16)
+    pages, compiled = _paged_step(cfg, one_chip, 128, 16)
+    hlo = compiled.as_text()
     pool = math.prod(pages)
     layer = pool // pages[0]
     moved = []
@@ -141,3 +143,72 @@ def test_decode_step_leaves_the_kv_pool_in_place(one_chip,
     pairs = dict(re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases.group(1)))
     assert set(pairs) == {"1", "2"}, pairs
     assert all(params[int(p)] == pool for p in pairs.values()), pairs
+
+
+# The benchmark's qwen2.5-14b-8layer: Qwen2.5-14B at its published widths
+# (40 query and 8 KV heads of 128, a group of 5; QKV biases; untied head),
+# cut to 8 of its 48 layers, served in bf16 at 32 rows of 16 pages.
+QWEN_CUT = dict(num_layers=8, param_dtype=jnp.bfloat16)
+HBM = 16 * 10**9            # one v5e chip
+
+
+def _resident(compiled):
+    """Bytes a compiled program holds on the device while it runs: its
+    arguments, what it writes that is not written in place, and its
+    temporaries."""
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def _nbytes(tree):
+    return sum(math.prod(x.shape) * jnp.dtype(x.dtype).itemsize
+               for x in jax.tree.leaves(tree))
+
+
+def test_qwen_cut_decode_step_compiles_at_group_5_and_fits(
+        one_chip, no_persistent_cache):
+    cfg = dataclasses.replace(get_config("qwen2.5-14b"), **QWEN_CUT)
+    assert (cfg.num_heads // cfg.num_kv_heads, cfg.head_dim) == (5, 128)
+    pages, compiled = _paged_step(cfg, one_chip, 32, 16)
+    assert "tpu_custom_call" in compiled.as_text()
+    weights = _nbytes(abstract_params(cfg))
+    pools = 2 * math.prod(pages) * 2        # K and V, bf16
+    assert weights == pytest.approx(7.52e9, rel=1e-3)
+    assert pools == pytest.approx(2.15e9, rel=1e-2)
+    # the weights and both pools are arguments; the pools are donated
+    assert weights + pools <= compiled.memory_analysis(
+        ).argument_size_in_bytes
+    assert _resident(compiled) < HBM
+
+
+def test_qwen_cut_longest_prefill_and_page_scatter_fit(one_chip,
+                                                        no_persistent_cache):
+    """The cell's longest prompt (1500 tokens, a cache of 2012 positions)
+    through the batch-1 prefill and the page scatter, each beside what
+    stays on the device while it runs: the pool beside the prefill, the
+    weights beside the scatter."""
+    cfg = dataclasses.replace(get_config("qwen2.5-14b"), **QWEN_CUT)
+    params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip),
+                          abstract_params(cfg))
+    tokens = {"tokens": _spec((1, 1500), jnp.int32, one_chip)}
+    pages = transformer.paged_kv_shape(cfg, 32 * 16 + 1,
+                                       DEFAULT_BLOCK_TOKENS)
+
+    def prefill(p, b):
+        return transformer.prefill(p, b, cfg, max_len=2012)
+
+    pre = jax.jit(prefill).lower(params, tokens).compile()
+    cache = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip),
+                         jax.eval_shape(prefill, params, tokens)[1])
+
+    def scatter(c, kp, vp, pg, off):
+        return transformer.scatter_prefill_pages(c, cfg, kp, vp, pg, off)
+
+    pos = _spec((1500,), jnp.int32, one_chip)
+    sc = jax.jit(scatter, donate_argnums=(1, 2)).lower(
+        cache, _spec(pages, jnp.bfloat16, one_chip),
+        _spec(pages, jnp.bfloat16, one_chip), pos, pos).compile()
+    pools = 2 * math.prod(pages) * 2
+    assert _resident(pre) + pools < HBM
+    assert _resident(sc) + _nbytes(params) < HBM
