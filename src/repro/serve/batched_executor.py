@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention.paged_attention import page_span
 from repro.models import model, transformer
 from repro.serve.kv_cache import FLASH_ATTENTION_BLOCK_K, PagedKVCache
 from repro.serve.spans import span
@@ -115,6 +116,10 @@ class JaxBatchedExecutor:
         self._len = np.zeros((n_slots,), np.int32)
         self._tables = np.full((n_slots, self.nb_max), self.null_page,
                                np.int32)
+        # running totals over decode calls: pages the paged kernel walks
+        # (each live row's pages), and what a (rows, pages) grid steps
+        self.pages_walked = 0
+        self.pages_gridded = 0
 
     # ---- introspection ----------------------------------------------------
     def decode_compiles(self) -> int:
@@ -162,13 +167,23 @@ class JaxBatchedExecutor:
 
     def decode(self, reqs: Sequence) -> Tuple[List[int], float]:
         t0 = self.clock()
-        with span("serve.decode"):
+        for r in reqs:
+            self._len[self.rows[r.rid]] = self.kv.seq_len(r.rid)
+        # per layer: the paged kernel walks each live row's pages, where a
+        # (rows, pages) grid would step every page of every row
+        first, stop = page_span(self._len, self.block_tokens,
+                                self.cfg.attention_window or 0)
+        walked = int(np.sum(stop - first))
+        gridded = self.n_slots * self.nb_max
+        self.pages_walked += walked
+        self.pages_gridded += gridded
+        with span("serve.decode", pages_walked=walked,
+                  pages_gridded=gridded):
             with span("serve.decode.prepare"):
                 # refresh the gather map from the allocator (the engine's
                 # append_token may have claimed fresh blocks since last step)
                 for r in reqs:
                     row = self.rows[r.rid]
-                    self._len[row] = self.kv.seq_len(r.rid)
                     table = self.kv.block_table(r.rid)
                     self._tables[row, :len(table)] = table
                 args = (jnp.asarray(self._tok), jnp.asarray(self._len),

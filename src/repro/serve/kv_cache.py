@@ -12,9 +12,9 @@ worst-case ``prompt + max_new`` a dense per-slot cache must reserve.
 the Pallas flash-attention kernel
 (``repro.kernels.flash_attention``): the paged attention kernel
 (``repro.kernels.paged_attention``) consumes the KV cache one
-(head_dim, block_k) VMEM page per grid step, tokens in the lanes, so
-sizing allocator blocks to the kernel's kv tile means a block table maps
-1:1 onto kernel grid iterations with no partial-tile waste.
+(head_dim, block_k) VMEM page per loop iteration, tokens in the lanes,
+so sizing allocator blocks to the kernel's kv tile means a block table
+maps 1:1 onto kernel iterations with no partial-tile waste.
 
 Everything is deterministic: the free list is a LIFO stack, so the same
 admission/free sequence always yields the same block tables (the serve

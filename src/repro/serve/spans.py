@@ -13,7 +13,9 @@ Every span the serve path opens is named in ``NAMES``:
     ``serve.prefill.request`` per request (row, prefill, argmax, page ids,
     scatter dispatch), then ``serve.prefill.sync`` (the wait and the token
     reads);
-  * ``serve.decode``: one executor decode call; inside it
+  * ``serve.decode``: one executor decode call, with the paged kernel's
+    ``pages_walked`` and ``pages_gridded`` as its arguments (see
+    ``JaxBatchedExecutor.decode``); inside it
     ``serve.decode.prepare`` (gather-map refresh and the host-to-device
     copies), ``serve.decode.dispatch`` (the jitted step returning) and
     ``serve.decode.sync`` (the wait and the read-back);
@@ -36,9 +38,10 @@ NAMES = (
 _NULL = contextlib.nullcontext()
 
 
-def span(name: str):
-    """A host span ``name`` (see module doc)."""
+def span(name: str, **args):
+    """A host span ``name`` (see module doc); ``args`` become the trace
+    event's arguments."""
     jax = sys.modules.get("jax")
     if jax is None:
         return _NULL
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **args)

@@ -95,6 +95,38 @@ def test_paged_step_kernel_matches_ref_logits():
                                    np.asarray(r, np.float32), atol=1e-2)
 
 
+def test_page_counters_count_each_live_rows_pages():
+    """``pages_walked`` sums ceil(len / block_tokens) over the live rows
+    of every decode call, read off the allocator as the call starts;
+    ``pages_gridded`` is W x nb_max a call.  Prompts cross page ends
+    while they decode, and a fifth request waits for a row."""
+    cfg = get_smoke("smollm-135m")
+    max_len, n_slots = 300, 4
+    ex = JaxBatchedExecutor(cfg, max_len, n_slots, attn_impl="ref")
+    rng = np.random.default_rng(5)
+    reqs = [ServeRequest(rid=i, prompt_len=p, max_new=n, t_submit=0.0,
+                         prompt=rng.integers(0, cfg.vocab_size, p)
+                         .astype(np.int32))
+            for i, (p, n) in enumerate([(125, 8), (3, 6), (250, 9),
+                                        (129, 2), (60, 4)])]
+    seen = {"calls": 0, "walked": 0, "most": 0}
+    decode = ex.decode
+
+    def counted(rs):
+        pages = [-(-ex.kv.seq_len(r.rid) // ex.block_tokens) for r in rs]
+        seen["calls"] += 1
+        seen["walked"] += sum(pages)
+        seen["most"] = max(seen["most"], *pages)
+        return decode(rs)
+
+    ex.decode = counted
+    ContinuousServeEngine(n_slots, ex, slo=NO_SLO, kv_cache=ex.kv).run(reqs)
+    assert seen["most"] == 3 and ex.nb_max == 3
+    assert ex.pages_walked == seen["walked"]
+    assert ex.pages_gridded == seen["calls"] * n_slots * ex.nb_max
+    assert 0 < ex.pages_walked < ex.pages_gridded
+
+
 def test_make_executor_falls_back_for_unpaged_families():
     cfg = get_smoke("rwkv6-3b")
     assert not model.supports_paged_decode(cfg, MAX_LEN)

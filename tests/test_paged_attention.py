@@ -88,6 +88,41 @@ def test_inactive_rows_output_exact_zeros():
         assert np.all(np.isfinite(np.asarray(out)))
 
 
+@pytest.mark.parametrize("b,window,lengths", [
+    (8, 0, [0, 0, 19, 0, 0, 0, 7, 0]),        # most rows inactive
+    (4, 0, [9, 1, 17, 24]),                   # pages past each row's last
+    (3, 12, [30, 26, 32]),                    # window opens in page 3
+], ids=["most_inactive", "past_last", "window_third_page"])
+def test_pages_outside_the_span_are_never_read(b, window, lengths):
+    """Every page a row cannot attend to — past its last page, before its
+    window's first, or any page of an inactive row — holds NaN in every
+    layer: the kernel's output is still the reference's over finite
+    pages, and its pools the reference's write into the poisoned ones."""
+    hq, hkv, d, bt, nb, layer = 4, 2, 16, 8, 4, 1
+    q, k, v, kp, vp, bt_m, lens = _mk(17 + b, b, hq, hkv, d, b * nb + 1, bt,
+                                      nb, lengths)
+    tables = np.asarray(bt_m)
+    dead = [tables[r, p] for r, n in enumerate(lengths) for p in range(nb)
+            if not (max(n - window, 0) // bt if window else 0)
+            <= p < -(-n // bt)]
+    assert dead
+    kp_nan = kp.at[:, :, np.asarray(dead)].set(jnp.nan)
+    vp_nan = vp.at[:, :, np.asarray(dead)].set(jnp.nan)
+    out, ko, vo = paged_attention_decode(q, k, v, kp_nan, vp_nan, layer,
+                                         bt_m, lens, window=window,
+                                         impl="kernel", interpret=True)
+    want, _, _ = paged_attention_ref(q, k, v, kp, vp, layer, bt_m, lens,
+                                     window=window)
+    _, want_k, want_v = paged_attention_ref(q, k, v, kp_nan, vp_nan, layer,
+                                            bt_m, lens, window=window)
+    assert np.all(np.isfinite(np.asarray(out)))
+    np.testing.assert_allclose(out, want, atol=3e-6)
+    np.testing.assert_array_equal(ko, want_k)
+    np.testing.assert_array_equal(vo, want_v)
+    inactive = [r for r, n in enumerate(lengths) if n == 0]
+    assert np.all(np.asarray(out)[inactive] == 0.0)
+
+
 @pytest.mark.parametrize("impl", ["kernel", "ref"])
 @pytest.mark.parametrize("layer", [0, 1, 2])
 def test_layer_index_picks_its_layer(impl, layer):
@@ -195,7 +230,7 @@ def test_paged_attention_property(seed, g, window, bt):
 
 def test_kernel_kv_tile_pins_to_allocator_block_size():
     """Allocator pages ARE kernel kv tiles: the three constants that make
-    block tables map 1:1 onto kernel grid iterations must stay equal."""
+    block tables map 1:1 onto kernel page iterations must stay equal."""
     assert DEFAULT_BLOCK_TOKENS == FLASH_ATTENTION_BLOCK_K
     assert PagedKVCache(1).block_tokens == DEFAULT_BLOCK_TOKENS
 

@@ -23,8 +23,9 @@ MAX_LEN = 32
 
 @pytest.fixture(scope="module")
 def served(tmp_path_factory):
-    """(executor, {span name: [(start, end)]}) of one traced run, which
-    prefills each of its four requests once."""
+    """(executor, {span name: [(start, end)]}, the ``serve.decode``
+    events' arguments) of one traced run, which prefills each of its four
+    requests once."""
     cfg = get_smoke("smollm-135m")
     ex = JaxBatchedExecutor(cfg, MAX_LEN, 3)
     rng = np.random.default_rng(3)
@@ -38,13 +39,15 @@ def served(tmp_path_factory):
     path, = glob.glob(str(d / "**" / "*.xplane.pb"), recursive=True)
     host, = [p for p in jax.profiler.ProfileData.from_file(path).planes
              if p.name == "/host:CPU"]
-    found = {}
+    found, args = {}, []
     for line in host.lines:
         for ev in line.events:
             if ev.name in spans.NAMES:
                 found.setdefault(ev.name, []).append(
                     (ev.start_ns, ev.start_ns + ev.duration_ns))
-    return ex, found
+            if ev.name == "serve.decode":
+                args.append(dict(ev.stats))
+    return ex, found, args
 
 
 def _within(inner, outers):
@@ -52,12 +55,12 @@ def _within(inner, outers):
 
 
 def test_every_span_lands_on_the_host_plane(served):
-    _, found = served
+    _, found, _ = served
     assert set(found) == set(spans.NAMES)
 
 
 def test_executor_spans_nest_in_their_call(served):
-    _, found = served
+    _, found, _ = served
     for call in ("serve.prefill", "serve.decode"):
         for name in spans.NAMES:
             if name.startswith(call + "."):
@@ -77,7 +80,7 @@ def test_executor_spans_nest_in_their_call(served):
 
 
 def test_engine_spans_lie_outside_the_executor_calls(served):
-    _, found = served
+    _, found, _ = served
     calls = found["serve.prefill"] + found["serve.decode"]
     for name in ("serve.engine.admit", "serve.engine.kv_grow",
                  "serve.engine.detach"):
@@ -85,8 +88,20 @@ def test_engine_spans_lie_outside_the_executor_calls(served):
             assert all(e <= cs or ce <= s for cs, ce in calls)
 
 
+def test_decode_span_carries_the_page_counts(served):
+    """Each ``serve.decode`` event carries the pages the kernel walks and
+    the pages a (rows, pages) grid would step; summed, the executor's
+    running totals."""
+    ex, found, args = served
+    assert len(args) == len(found["serve.decode"])
+    assert sum(int(a["pages_walked"]) for a in args) == ex.pages_walked > 0
+    assert all(int(a["pages_gridded"]) == ex.n_slots * ex.nb_max
+               for a in args)
+    assert sum(int(a["pages_gridded"]) for a in args) == ex.pages_gridded
+
+
 def test_executor_programs_carry_their_names(served):
-    ex, _ = served
+    ex, _, _ = served
     batch = {"tokens": jnp.zeros((1, 8), jnp.int32)}
     assert ex._prefill.lower(ex.params, batch).as_text().startswith(
         "module @jit_prefill")

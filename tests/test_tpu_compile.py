@@ -24,6 +24,7 @@ from repro.models.init import abstract_params
 
 W = 8           # batch width of the serving executor
 NB = 4          # pages per block-table row
+VMEM_SCOPED = 16 * 2**20    # v5e's default scoped VMEM a kernel may use
 
 
 @pytest.fixture(scope="module")
@@ -212,3 +213,31 @@ def test_qwen_cut_longest_prefill_and_page_scatter_fit(one_chip,
     pools = 2 * math.prod(pages) * 2
     assert _resident(pre) + pools < HBM
     assert _resident(sc) + _nbytes(params) < HBM
+
+
+def _kernel_vmem(hlo):
+    """VMEM bytes the paged-attention custom call holds, from the scoped
+    memory configs of its backend config (memory space 1 is VMEM)."""
+    line, = [ln for ln in hlo.splitlines()
+             if "tpu_custom_call" in ln and "paged_attention" in ln]
+    used = re.search(r'"used_scoped_memory_configs":\[(.*?)\]', line)
+    return sum(int(size) for space, size in re.findall(
+        r'"memory_space":"(\d+)","offset":"\d+","size":"(\d+)"',
+        used.group(1)) if space == "1")
+
+
+@pytest.mark.parametrize("arch,cut,width", [
+    ("smollm-135m", {}, 128),               # group 3, head_dim 64
+    ("qwen2.5-14b", QWEN_CUT, 32),          # group 5, head_dim 128
+])
+def test_paged_kernel_scratch_fits_vmem(one_chip, no_persistent_cache,
+                                        arch, cut, width):
+    """At the benchmark cells' widths (16 pages a row) the decode step
+    holds the kernel, whose VMEM holds two slots of one page of every kv
+    head for K and for V, within the scoped VMEM of a v5e."""
+    cfg = dataclasses.replace(get_config(arch), **cut)
+    _, compiled = _paged_step(cfg, one_chip, width, 16)
+    vmem = _kernel_vmem(compiled.as_text())
+    slots = 2 * 2 * cfg.num_kv_heads * cfg.head_dim * DEFAULT_BLOCK_TOKENS \
+        * jnp.dtype(cfg.compute_dtype).itemsize
+    assert slots <= vmem <= VMEM_SCOPED, (slots, vmem)
