@@ -16,8 +16,8 @@ import traceback
 from benchmarks import (advisor_rank, fig4_job_sizes, fig12_pg_compiler,
                         fig14_rg_optimizations, fig15_rg_phases,
                         fig16_sg_by_size, fleet_scale, ledger_scale,
-                        overlap_speedup, paged_decode, roofline,
-                        scenario_sweep, serve_scale, table2_mpg_composition)
+                        overlap_speedup, roofline, scenario_sweep,
+                        serve_scale, table2_mpg_composition)
 from benchmarks.common import RESULTS
 
 BENCHES = [
@@ -30,7 +30,6 @@ BENCHES = [
     ("ledger_scale", ledger_scale.main),
     ("fleet_scale", fleet_scale.main),
     ("serve_scale", serve_scale.main),
-    ("paged_decode", paged_decode.main),
     ("scenario_sweep", scenario_sweep.main),
     ("advisor_rank", advisor_rank.main),
     ("overlap_speedup", overlap_speedup.main),

@@ -18,16 +18,7 @@ Sections:
   * ``diurnal`` / ``bursty`` — large-request-count runs (the paper's
     fluctuating-demand serving story, Fig. 15): p50/p99 TTFT and
     per-token latency alongside SG/RG/PG and SLO-goodput for both
-    engines;
-  * ``batched_tiny`` / ``batched_full`` — the *real-model* batched
-    paged-decode A/B: the same continuous engine drives
-    ``JaxBatchedExecutor`` (one jitted decode at fixed width over the
-    allocator's block tables) vs ``JaxSlotExecutor`` (per-slot batch-1)
-    over an identical request stream, asserts token identity, and
-    records decode tokens/s for each arm plus their ratio.  These
-    sections need JAX; when it is not importable (the numpy-only
-    benchmark CI job) the committed sections are preserved untouched and
-    ``--check`` gates on them structurally.
+    engines.
 
 Every section records a config fingerprint so numbers are never compared
 across silently different workloads.
@@ -38,14 +29,12 @@ import argparse
 import hashlib
 import json
 import pathlib
-import sys
 import time
-from typing import Dict, Optional
+from typing import Dict
 
 from repro.fleet.scenarios import SCENARIOS, request_arrivals
 from repro.serve import (ContinuousServeEngine, ServeSLO, SimulatedExecutor,
                          run_static, synthetic_requests)
-from repro.serve.engine import NO_SLO, ServeRequest
 
 from benchmarks.common import save_json
 
@@ -67,18 +56,6 @@ FULL = {"requests": 20_000, "span": 1500.0, "n_slots": 16,
         "slo_tpot": 0.05, "seed": 42}
 # same load point at 1/10 the population for `benchmarks.run` quick mode
 QUICK = dict(FULL, requests=2_000, span=150.0)
-
-# real-model batched paged-decode A/B (needs JAX; attn_impl="ref" is the
-# XLA gather path — the Pallas kernel's interpret mode is a correctness
-# vehicle, not a CPU performance one).  Prompt lengths come from a small
-# discrete set so the per-length prefill jit cache stays bounded; the
-# *decode* side is what the section measures, and both executors decode
-# at a single compiled shape.
-BATCHED_TINY = {"arch": "smollm-135m", "requests": 24, "n_slots": 4,
-                "max_len": 64, "prompt_lens": [4, 8, 12, 16],
-                "max_new": [4, 16], "attn_impl": "ref", "seed": 42}
-BATCHED_FULL = dict(BATCHED_TINY, requests=128, n_slots=8, max_len=96,
-                    prompt_lens=[8, 16, 32, 48], max_new=[8, 32])
 
 
 def _fingerprint(cfg: Dict) -> str:
@@ -126,131 +103,21 @@ def run_section(cfg: Dict, arrival: str) -> Dict:
     }
 
 
-# ---------------------------------------------------------------------------
-# real-model batched paged-decode A/B
-# ---------------------------------------------------------------------------
-
-def _batched_requests(cfg: Dict, model_cfg, seed: int):
-    import numpy as np
-
-    rng = np.random.default_rng(seed)
-    nlo, nhi = cfg["max_new"]
-    reqs = []
-    for i in range(cfg["requests"]):
-        plen = int(rng.choice(cfg["prompt_lens"]))
-        reqs.append(ServeRequest(
-            rid=i, prompt_len=plen, max_new=int(rng.integers(nlo, nhi + 1)),
-            t_submit=0.0,
-            prompt=rng.integers(0, model_cfg.vocab_size, plen)
-            .astype(np.int32)))
-    return reqs
-
-
-def _instrument_decode(ex) -> Dict:
-    """Wrap ``ex.decode`` to accumulate executor-measured decode cost and
-    token count — the engine-agnostic source of decode tokens/s."""
-    stats = {"decode_tokens": 0, "decode_s": 0.0, "decode_calls": 0}
-    orig = ex.decode
-
-    def decode(reqs):
-        toks, cost = orig(reqs)
-        stats["decode_tokens"] += len(toks)
-        stats["decode_s"] += cost
-        stats["decode_calls"] += 1
-        return toks, cost
-
-    ex.decode = decode
-    return stats
-
-
-def run_batched_section(cfg: Dict) -> Dict:
-    """Batched paged-decode vs per-slot batch-1 decode, same continuous
-    engine, identical request stream: token identity asserted, decode
-    tokens/s measured off the executors' own cost clocks."""
-    from repro.configs import get_smoke
-    from repro.serve.batched_executor import JaxBatchedExecutor
-    from repro.serve.jax_executor import JaxSlotExecutor
-
-    mcfg = get_smoke(cfg["arch"])
-    n_slots, max_len = cfg["n_slots"], cfg["max_len"]
-
-    def run_arm(ex):
-        kv = getattr(ex, "kv", None)
-        # warmup run compiles every jitted path at the serving width
-        warm = _batched_requests(cfg, mcfg, cfg["seed"] + 1)[: 2 * n_slots]
-        ContinuousServeEngine(n_slots, ex, slo=NO_SLO, kv_cache=kv).run(warm)
-        stats = _instrument_decode(ex)
-        reqs = _batched_requests(cfg, mcfg, cfg["seed"])
-        t0 = time.perf_counter()
-        rep = ContinuousServeEngine(n_slots, ex, slo=NO_SLO,
-                                    kv_cache=kv).run(reqs)
-        wall = time.perf_counter() - t0
-        toks = {r.rid: list(r.out_tokens) for r in reqs}
-        tps = stats["decode_tokens"] / max(stats["decode_s"], 1e-12)
-        row = {
-            "decode_tokens": stats["decode_tokens"],
-            "decode_s": round(stats["decode_s"], 6),
-            "decode_calls": stats["decode_calls"],
-            "decode_tokens_per_s": round(tps, 1),
-            "tokens": rep.tokens,
-            "requests": rep.requests,
-            "bench_wall_s": round(wall, 3),
-        }
-        return row, toks
-
-    per_row, per_toks = run_arm(JaxSlotExecutor(mcfg, max_len))
-    per_row["executor"] = "JaxSlotExecutor"
-    bat_ex = JaxBatchedExecutor(mcfg, max_len, n_slots,
-                                attn_impl=cfg["attn_impl"])
-    bat_row, bat_toks = run_arm(bat_ex)
-    bat_row["executor"] = "JaxBatchedExecutor"
-    bat_row["decode_compiles"] = bat_ex.decode_compiles()
-    bat_row["kv_cache"] = bat_ex.kv.stats.as_dict()
-    identical = per_toks == bat_toks
-    assert identical, "batched decode diverged from per-slot tokens"
-    ratio = (bat_row["decode_tokens_per_s"]
-             / max(per_row["decode_tokens_per_s"], 1e-12))
-    return {
-        "config": dict(cfg),
-        "config_fingerprint": _fingerprint(cfg),
-        "per_slot": per_row,
-        "batched": bat_row,
-        "decode_tokens_per_s_ratio": round(ratio, 3),
-        "tokens_identical": identical,
-    }
-
-
-def _maybe_batched_section(cfg: Dict) -> Optional[Dict]:
-    try:
-        import jax  # noqa: F401
-    except ModuleNotFoundError:
-        print("serve_scale: jax unavailable — batched sections kept from "
-              "the committed BENCH_serve.json", file=sys.stderr)
-        return None
-    return run_batched_section(cfg)
-
-
 def _load_committed() -> Dict:
     if BENCH_PATH.exists():
         return json.loads(BENCH_PATH.read_text())
     return {}
 
 
-def check(fresh_tiny: Dict, committed: Dict,
-          fresh_batched: Optional[Dict] = None) -> None:
+def check(fresh_tiny: Dict, committed: Dict) -> None:
     """CI gate: (1) the ordering invariant — continuous must beat static
     on tokens delivered within SLO at equal capacity; (2) the margin must
-    not collapse vs the committed baseline; (3) the committed batched
-    paged-decode sections must stay token-identical with a batching win
-    (decode tokens/s ratio > 1) at width >= 4; (4) a freshly-run batched
-    section (JAX available) must be token-identical with exactly one
-    decode compile."""
+    not collapse vs the committed baseline."""
     margin = fresh_tiny["slo_tokens_margin"]
     if margin <= 0:
         raise SystemExit(
             f"serve_scale --check FAILED: continuous does not beat static "
             f"on within-SLO tokens (margin {margin})")
-    _check_batched(committed, fresh_batched)
     base = committed.get("tiny")
     if not base:
         print("serve_scale --check: no committed baseline; ordering "
@@ -270,42 +137,6 @@ def check(fresh_tiny: Dict, committed: Dict,
     print(f"serve_scale --check OK: {msg}")
 
 
-def _check_batched(committed: Dict, fresh_batched: Optional[Dict]) -> None:
-    """Structural gates on the committed batched sections (no JAX needed)
-    plus determinism/compile gates on a fresh run when JAX is present.
-    The fresh gates avoid wall-clock ratio thresholds — CI runner timing
-    is noisy — and pin what must be exact: token identity and the single
-    decode compile."""
-    for name, sec in sorted(committed.items()):
-        if not (isinstance(sec, dict) and "decode_tokens_per_s_ratio" in sec):
-            continue
-        if sec.get("tokens_identical") is not True:
-            raise SystemExit(
-                f"serve_scale --check FAILED: committed {name} is not "
-                "token-identical between batched and per-slot")
-        ratio = sec["decode_tokens_per_s_ratio"]
-        if sec["config"]["n_slots"] >= 4 and ratio <= 1.0:
-            raise SystemExit(
-                f"serve_scale --check FAILED: committed {name} shows no "
-                f"batching win (decode tokens/s ratio {ratio} at width "
-                f"{sec['config']['n_slots']})")
-        print(f"serve_scale --check OK: committed {name} ratio {ratio} "
-              f"at width {sec['config']['n_slots']}")
-    if fresh_batched is None:
-        return
-    if fresh_batched["tokens_identical"] is not True:
-        raise SystemExit("serve_scale --check FAILED: fresh batched run "
-                         "is not token-identical to per-slot")
-    compiles = fresh_batched["batched"]["decode_compiles"]
-    if compiles != 1:
-        raise SystemExit(
-            f"serve_scale --check FAILED: batched decode compiled "
-            f"{compiles} times (admission/detach must not recompile)")
-    print(f"serve_scale --check OK: fresh batched_tiny token-identical, "
-          f"1 decode compile, ratio "
-          f"{fresh_batched['decode_tokens_per_s_ratio']}")
-
-
 def main(quick: bool = False, tiny: bool = False,
          do_check: bool = False) -> Dict:
     committed = _load_committed()
@@ -314,18 +145,12 @@ def main(quick: bool = False, tiny: bool = False,
     fresh_tiny = run_section(TINY, TINY["arrival"])
     bench["tiny"] = fresh_tiny
     sections = {"tiny": fresh_tiny}
-    fresh_batched = _maybe_batched_section(BATCHED_TINY)
-    if fresh_batched is not None:
-        sections["batched_tiny"] = bench["batched_tiny"] = fresh_batched
     if do_check:
-        check(fresh_tiny, committed, fresh_batched)
+        check(fresh_tiny, committed)
     if not tiny:
         cfg = QUICK if quick else FULL
         for arrival in ("diurnal", "bursty"):
             sections[arrival] = bench[arrival] = run_section(cfg, arrival)
-        if fresh_batched is not None:
-            sections["batched_full"] = bench["batched_full"] = \
-                run_batched_section(BATCHED_FULL)
     bench["version"] = 1
     bench["generated_by"] = "benchmarks/serve_scale.py"
     BENCH_PATH.write_text(json.dumps(bench, indent=1, sort_keys=True) + "\n")
@@ -341,10 +166,6 @@ def main(quick: bool = False, tiny: bool = False,
             sections["bursty"]["slo_tokens_margin"]
         derived["bursty_p99_ttft_continuous"] = \
             sections["bursty"]["continuous"]["ttft_s"]["p99"]
-    for name in ("batched_tiny", "batched_full"):
-        if name in sections:
-            derived[f"{name}_decode_tps_ratio"] = \
-                sections[name]["decode_tokens_per_s_ratio"]
     print(f"serve_scale,{wall_us:.1f},{json.dumps(derived, sort_keys=True)}")
     return bench
 

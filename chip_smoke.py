@@ -10,9 +10,10 @@ One chip runs, in one process and in this order:
     device is a TPU;
   * kernel vs ref — the paged-attention Pallas kernel against the XLA
     gather reference on the same bf16 inputs;
-  * serve — ``repro.launch.serve.run_continuous_server`` with the batched
-    paged-decode executor, then the decode program's kernel, its compile
-    count, and one decode step's logits under the kernel vs the reference;
+  * serve — ``repro.launch.serve.run_continuous_server``, which picks the
+    batched paged-decode executor for this family, then the decode
+    program's kernel, its compile count, and one decode step's logits
+    under the kernel vs the reference;
   * train — ``repro.runtime.orchestrator.Orchestrator``, as
     ``repro.launch.train`` builds it, for a few steps and one checkpoint.
 
@@ -110,10 +111,10 @@ def check_device(need: int) -> dict:
 
 
 def phase_kernel_vs_ref(cfg, seed: int) -> None:
-    from repro.kernels.paged_attention.ops import (DEFAULT_BLOCK_TOKENS,
-                                                   paged_attention_decode)
+    from repro.kernels.paged_attention.ops import paged_attention_decode
+    from repro.serve.kv_cache import BLOCK_TOKENS
 
-    bt, nb, n_layers, layer = DEFAULT_BLOCK_TOKENS, 4, 2, 1
+    bt, nb, n_layers, layer = BLOCK_TOKENS, 4, 2, 1
     n_pages = W * nb + 1
     # an inactive row, lengths off and on page edges, and a full table
     lengths = jnp.asarray([0, 1, 100, bt, bt + 1, 300, 383, nb * bt],
@@ -151,18 +152,25 @@ def phase_kernel_vs_ref(cfg, seed: int) -> None:
 
 def phase_serve(cfg, seed: int) -> None:
     from repro.kernels.paged_attention.ops import resolve_impl
-    from repro.launch.serve import Request, run_continuous_server
+    from repro.launch.serve import run_continuous_server
     from repro.models import model
+    from repro.serve import ServeRequest
+    from repro.serve.batched_executor import JaxBatchedExecutor
 
     rng = np.random.default_rng(seed)
     t_submit = time.monotonic()
-    reqs = [Request(i, rng.integers(0, cfg.vocab_size, PROMPT_LEN)
-                    .astype(np.int32), MAX_NEW, t_submit=t_submit)
+    reqs = [ServeRequest(rid=i, prompt_len=PROMPT_LEN, max_new=MAX_NEW,
+                         t_submit=t_submit,
+                         prompt=rng.integers(0, cfg.vocab_size, PROMPT_LEN)
+                         .astype(np.int32))
             for i in range(N_REQUESTS)]
     t0 = time.monotonic()
-    ex, rep = run_continuous_server(cfg, reqs, W, MAX_NEW, PROMPT_LEN,
-                                    slo_ttft=0.0, slo_tpot=0.0,
-                                    executor_kind="batched")
+    engine, rep = run_continuous_server(cfg, reqs, W, slo_ttft=0.0,
+                                        slo_tpot=0.0)
+    ex = engine.executor
+    check(isinstance(ex, JaxBatchedExecutor),
+          f"make_executor picked {type(ex).__name__}, not the batched "
+          f"paged-decode executor")
     say(f"serve: {rep['requests']} requests, {rep['tokens']} tokens in "
         f"{time.monotonic() - t0:.1f}s wall (smoke timing, compiles "
         f"included)")

@@ -18,12 +18,10 @@ import functools
 
 import jax
 
-from repro.kernels.paged_attention.paged_attention import (
-    DEFAULT_BLOCK_TOKENS, paged_attention)
+from repro.kernels.paged_attention.paged_attention import paged_attention
 from repro.kernels.paged_attention.ref import paged_attention_ref
 
-__all__ = ["DEFAULT_BLOCK_TOKENS", "paged_attention_decode",
-           "resolve_impl"]
+__all__ = ["paged_attention_decode", "resolve_impl"]
 
 
 def resolve_impl(impl: str = "auto") -> str:

@@ -28,9 +28,8 @@ empty rows instead of recompiling at a new width.
 kernel's kv tile: each loop iteration attends over exactly one page, so
 allocator blocks map 1:1 onto kernel iterations with no partial-tile
 waste, and the tile is lane-dense at every head_dim.  The allocator's
-default (``FLASH_ATTENTION_BLOCK_K`` = 128, the Pallas flash-attention
-kv tile) keeps both kernels fed whole MXU-aligned tiles; a pin test
-holds the two constants equal.
+default, ``repro.serve.kv_cache.BLOCK_TOKENS`` (128), is one full lane
+row; the batched executor builds its pool at that size.
 
 TPU adaptation notes: the grid is one step per batch row, ``(b,)``; the
 q and output blocks hold all of the row's heads, ``(1, hkv, g, d)``.
@@ -59,11 +58,6 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 NEG_INF = -1e30
-
-# The kernel's kv tile == the serve allocator's default page size ==
-# the flash-attention kernel's block_k (pinned against each other and
-# against repro.serve.kv_cache.FLASH_ATTENTION_BLOCK_K by test).
-DEFAULT_BLOCK_TOKENS = 128
 
 
 def page_span(length, block_tokens: int, window: int = 0):

@@ -7,12 +7,11 @@ numpy-only benchmark CI jobs.)"""
 from repro.serve.engine import (NO_SLO, ContinuousServeEngine, ServeReport,
                                 ServeRequest, ServeSLO, SimulatedExecutor,
                                 run_static, synthetic_requests)
-from repro.serve.kv_cache import (FLASH_ATTENTION_BLOCK_K, KVCacheStats,
+from repro.serve.kv_cache import (BLOCK_TOKENS, KVCacheStats,
                                   OutOfBlocksError, PagedKVCache)
 
 __all__ = [
     "NO_SLO", "ContinuousServeEngine", "ServeReport", "ServeRequest",
     "ServeSLO", "SimulatedExecutor", "run_static", "synthetic_requests",
-    "FLASH_ATTENTION_BLOCK_K", "KVCacheStats", "OutOfBlocksError",
-    "PagedKVCache",
+    "BLOCK_TOKENS", "KVCacheStats", "OutOfBlocksError", "PagedKVCache",
 ]

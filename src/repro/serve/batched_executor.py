@@ -52,7 +52,7 @@ import numpy as np
 
 from repro.kernels.paged_attention.paged_attention import page_span
 from repro.models import model, transformer
-from repro.serve.kv_cache import FLASH_ATTENTION_BLOCK_K, PagedKVCache
+from repro.serve.kv_cache import BLOCK_TOKENS, PagedKVCache
 from repro.serve.spans import span
 
 
@@ -70,7 +70,7 @@ class JaxBatchedExecutor:
         self.max_len = max_len
         self.n_slots = n_slots
         self.clock = clock
-        self.block_tokens = FLASH_ATTENTION_BLOCK_K
+        self.block_tokens = BLOCK_TOKENS
         self.nb_max = -(-max_len // self.block_tokens)
         n_blocks = n_slots * self.nb_max
         # the allocator the engine must share (kv_cache=executor.kv)

@@ -1,11 +1,12 @@
 """Continuous-batching serve engine with SLO-aware serving goodput.
 
 The paper's Fig. 15 shows serving Runtime Goodput trailing training
-because of fluctuating demand and batch bubbles.  The legacy serve loop
-(``repro.launch.serve.Server``) *creates* those losses by construction:
-fixed ``range(0, len(reqs), batch)`` groups, head-of-line blocking while
-a group assembles, and every request riding out ``max(r.max_new)`` of
-its batch.  This engine schedules around them:
+because of fluctuating demand and batch bubbles.  A static batch loop
+*creates* those losses by construction: fixed
+``range(0, len(reqs), batch)`` groups, head-of-line blocking while a
+group assembles, and every request riding out ``max(r.max_new)`` of its
+batch (``run_static`` below replays that policy).  This engine
+schedules around them:
 
   * **prefill/decode phase split** — admission prefills new requests as
     their own op; decode iterations run over whatever is live;
@@ -32,13 +33,13 @@ gap/overlap-free tiling property test).
 
 The engine runs in *virtual time*: every executor op returns its cost
 and the engine advances its clock by it.  With the simulated executor
-the whole run is deterministic bit-for-bit; with the per-slot JAX
-executor costs are measured off an injectable clock (the same
-``TickClock`` contract the legacy server uses).
+the whole run is deterministic bit-for-bit; the JAX executors measure
+their costs off an injectable clock (``repro.launch.serve.TickClock``
+makes those runs deterministic too).
 
-``run_static`` is the equal-capacity reference: the legacy fixed-group
-policy replayed through the identical executor, SLO, and accounting —
-the A/B behind ``BENCH_serve.json``.
+``run_static`` is the equal-capacity reference: the fixed-group policy
+replayed through the identical executor, SLO, and accounting — the A/B
+behind ``BENCH_serve.json``.
 """
 from __future__ import annotations
 
@@ -50,7 +51,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 from repro.core.attribution import _SHIFT, _exact
 from repro.core.goodput import Layer, Phase
 from repro.core.ledger import GoodputLedger
-from repro.serve.kv_cache import OutOfBlocksError, PagedKVCache
+from repro.serve.kv_cache import (BLOCK_TOKENS, OutOfBlocksError,
+                                  PagedKVCache)
 from repro.serve.spans import span
 
 try:
@@ -327,8 +329,8 @@ class ContinuousServeEngine:
         if self.kv is None:
             need = max((r.prompt_len + r.max_new for r in reqs), default=1)
             self.kv = PagedKVCache(
-                n_blocks=self.n_slots * max(
-                    1, -(-need // 128)), block_tokens=128)
+                n_blocks=self.n_slots * max(1, -(-need // BLOCK_TOKENS)),
+                block_tokens=BLOCK_TOKENS)
         kv = self.kv
         for r in reqs:
             if r.max_new < 1 or r.prompt_len < 1:

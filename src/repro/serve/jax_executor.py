@@ -8,16 +8,15 @@ mid-flight admission into a *batched* cache).  ``prefill`` and
 ``decode`` are jitted once at batch width 1 and reused for every slot.
 
 This trades MXU batching efficiency for exact continuous-batching
-semantics with the real program — the right trade for smoke-scale
-correctness runs.  Throughput modeling at scale lives in
-:class:`repro.serve.engine.SimulatedExecutor`; batched paged-attention
-decode over the block tables (the Pallas flash-attention kernel's
-``block_k`` tiles, which the allocator's block size mirrors) is the
-hardware path this executor stands in for.
+semantics with the real program.  It serves the families whose state
+block tables do not address — recurrent state (``ssm``/``hybrid``),
+encoder context (``encdec``/``vlm``) and windows narrower than the
+sequence; ``repro.serve.batched_executor.make_executor`` sends every
+other family to the batched paged-decode executor.
 
 Costs are measured off an injectable clock (``TickClock`` for
-deterministic tests, ``time.monotonic`` for real runs), read once per
-op — the same one-read-per-boundary contract as the fixed legacy server.
+deterministic tests, ``time.monotonic`` for real runs), read once at
+each op's start and once after its single host sync.
 """
 from __future__ import annotations
 

@@ -8,13 +8,12 @@ grows a request one block at a time as generation crosses block
 boundaries, so memory tracks *actual* sequence lengths instead of the
 worst-case ``prompt + max_new`` a dense per-slot cache must reserve.
 
-``block_tokens`` defaults to 128 — the MXU-aligned ``block_k`` tile of
-the Pallas flash-attention kernel
-(``repro.kernels.flash_attention``): the paged attention kernel
-(``repro.kernels.paged_attention``) consumes the KV cache one
-(head_dim, block_k) VMEM page per loop iteration, tokens in the lanes,
-so sizing allocator blocks to the kernel's kv tile means a block table
-maps 1:1 onto kernel iterations with no partial-tile waste.
+``block_tokens`` defaults to ``BLOCK_TOKENS`` (128): the batched
+executor's page pool holds each page as a ``(head_dim, block_tokens)``
+tile with the tokens in the lanes, and the paged-attention kernel
+(``repro.kernels.paged_attention``) reads the page size off the pool's
+shape, one page a loop iteration — so a block table maps 1:1 onto
+kernel iterations with no partial-tile waste.
 
 Everything is deterministic: the free list is a LIFO stack, so the same
 admission/free sequence always yields the same block tables (the serve
@@ -26,9 +25,9 @@ import dataclasses
 from typing import Dict, List
 
 
-# block_k of repro.kernels.flash_attention.flash_attention_bshd — keep in
-# sync (test_serve_engine pins this against the kernel's default).
-FLASH_ATTENTION_BLOCK_K = 128
+# Tokens a page: the page pool's lane tile (a full 128-lane vreg row),
+# which the paged-attention kernel reads off the pool's shape.
+BLOCK_TOKENS = 128
 
 
 class OutOfBlocksError(RuntimeError):
@@ -61,7 +60,7 @@ class PagedKVCache:
     """
 
     def __init__(self, n_blocks: int,
-                 block_tokens: int = FLASH_ATTENTION_BLOCK_K):
+                 block_tokens: int = BLOCK_TOKENS):
         if n_blocks <= 0:
             raise ValueError(f"n_blocks must be positive, got {n_blocks}")
         if block_tokens <= 0:

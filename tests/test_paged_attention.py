@@ -13,11 +13,10 @@ try:
 except ModuleNotFoundError:
     from tests._hypothesis_fallback import given, settings, st
 
-from repro.kernels.paged_attention.ops import (DEFAULT_BLOCK_TOKENS,
-                                               paged_attention_decode,
+from repro.kernels.paged_attention.ops import (paged_attention_decode,
                                                resolve_impl)
 from repro.kernels.paged_attention.ref import paged_attention_ref
-from repro.serve.kv_cache import FLASH_ATTENTION_BLOCK_K, PagedKVCache
+from repro.serve.kv_cache import BLOCK_TOKENS, PagedKVCache
 
 
 def _mk(seed, b, hq, hkv, d, n_pages, bt, nb, lengths, n_layers=2):
@@ -229,10 +228,16 @@ def test_paged_attention_property(seed, g, window, bt):
 # ---------------------------------------------------------------------------
 
 def test_kernel_kv_tile_pins_to_allocator_block_size():
-    """Allocator pages ARE kernel kv tiles: the three constants that make
-    block tables map 1:1 onto kernel page iterations must stay equal."""
-    assert DEFAULT_BLOCK_TOKENS == FLASH_ATTENTION_BLOCK_K
-    assert PagedKVCache(1).block_tokens == DEFAULT_BLOCK_TOKENS
+    """Allocator pages ARE kernel kv tiles: the kernel reads the page size
+    off the pool's lane axis, so the batched executor's pool, its
+    allocator and the default page size must agree."""
+    from repro.configs import get_smoke
+    from repro.serve.batched_executor import JaxBatchedExecutor
+
+    ex = JaxBatchedExecutor(get_smoke("smollm-135m"), 12, 2)
+    assert ex._kp.shape[-1] == ex._vp.shape[-1] == BLOCK_TOKENS
+    assert ex.block_tokens == ex.kv.block_tokens == BLOCK_TOKENS
+    assert PagedKVCache(1).block_tokens == BLOCK_TOKENS
 
 
 def test_resolve_impl_off_tpu_is_ref():

@@ -195,55 +195,8 @@ SERVE_AB_SECTION = {
     "slo_token_goodput_margin": positive,
 }
 
-# one executor arm of a batched paged-decode A/B section
-BATCHED_ARM = {
-    "executor": str, "decode_tokens": positive, "decode_s": non_negative,
-    "decode_calls": positive, "decode_tokens_per_s": positive,
-    "tokens": positive, "requests": positive, "bench_wall_s": non_negative,
-}
-
-# the real-model batched paged-decode A/B (benchmarks/serve_scale.py
-# run_batched_section): JaxBatchedExecutor vs JaxSlotExecutor over an
-# identical request stream through the same continuous engine
-BATCHED_AB_SECTION = {
-    "config": {"arch": str, "requests": positive, "n_slots": positive,
-               "max_len": positive, "attn_impl": str, "seed": int},
-    "config_fingerprint": str,
-    "per_slot": BATCHED_ARM,
-    "batched": {**BATCHED_ARM,
-                "decode_compiles": lambda x: x == 1,
-                "kv_cache": KV_CACHE},
-    "decode_tokens_per_s_ratio": positive,
-    # the PR acceptance invariant: batching must not change a single token
-    "tokens_identical": lambda x: x is True,
-}
-
-
-def ab_or_batched_section(x):
-    """serve_scale.json holds two section shapes: the simulated
-    continuous-vs-static A/B and the real-model batched paged-decode
-    A/B, distinguished by their headline metric."""
-    spec = (BATCHED_AB_SECTION
-            if isinstance(x, dict) and "decode_tokens_per_s_ratio" in x
-            else SERVE_AB_SECTION)
-    return not check(x, spec)
-
-
-PAGED_DECODE_POINT = {
-    "width": positive, "seq_len": positive, "iters": positive,
-    "per_slot_tokens_per_s": positive, "batched_tokens_per_s": positive,
-    "ratio": positive,
-}
-
 SERVE_SCHEMAS = {
-    "serve_scale.json": each_value(ab_or_batched_section),
-    "paged_decode.json": {
-        "arch": str, "attn_impl": str, "block_tokens": positive,
-        "sweep": lambda x: isinstance(x, list) and len(x) >= 2
-        and not [p for pt in x for p in check(pt, PAGED_DECODE_POINT)],
-        "checks": {"n_points": lambda x: x >= 2,
-                   "batched_wins_at_width_ge_4": lambda x: x is True},
-    },
+    "serve_scale.json": each_value(SERVE_AB_SECTION),
 }
 
 
@@ -303,28 +256,6 @@ def test_committed_serve_bench_has_continuous_ahead():
         assert c["n_slots"] == s["n_slots"]          # equal capacity
         assert c["tokens"] == s["tokens"]            # equal work
         assert c["tokens_within_slo"] > s["tokens_within_slo"], name
-
-
-def test_committed_serve_bench_shows_batched_decode_win():
-    """PR acceptance: the committed BENCH_serve.json's batched
-    paged-decode sections are token-identical to per-slot decode with a
-    single decode compile, and the full-width section shows the batching
-    win (decode tokens/s ratio > 1 at width >= 4)."""
-    path = REPO_ROOT / "BENCH_serve.json"
-    if not path.exists():
-        pytest.skip("BENCH_serve.json not committed in this checkout")
-    bench = json.loads(path.read_text())
-    sections = {k: v for k, v in bench.items()
-                if isinstance(v, dict) and "decode_tokens_per_s_ratio" in v}
-    assert {"batched_tiny", "batched_full"} <= set(sections)
-    for name, sec in sections.items():
-        problems = check(sec, BATCHED_AB_SECTION, f"BENCH_serve.{name}")
-        assert not problems, "\n".join(problems)
-        assert sec["tokens_identical"] is True, name
-        assert sec["per_slot"]["tokens"] == sec["batched"]["tokens"], name
-    full = sections["batched_full"]
-    assert full["config"]["n_slots"] >= 4
-    assert full["decode_tokens_per_s_ratio"] > 1.0
 
 
 RESILIENCE_ARM = {

@@ -1,99 +1,75 @@
-"""Serve-layer tests: tail-batch padding correctness (the double-count
-bug) and the serve-side goodput emitter (QUEUED/INIT/STEP/IDLE)."""
-import time
+"""Serve launcher tests: ``run_continuous_server`` drives the continuous
+engine over the real model, with the executor ``make_executor`` picks by
+family — batched paged decode for a dense decoder, per-slot batch-1
+decode for an encoder-decoder (encoder frames) and for a recurrent
+model (recurrent state).  Each family serves one shared run: six
+requests at batch 4, under an injected ``TickClock``."""
+import json
 
 import numpy as np
 import pytest
 
+from repro.configs import get_smoke
 from repro.core.goodput import Layer, Phase
-from repro.core.ledger import GoodputLedger
-from repro.launch.serve import Request, Server, TickClock, pad_group
+from repro.launch import serve
+from repro.launch.serve import TickClock, run_continuous_server
+from repro.serve import BLOCK_TOKENS, ServeRequest
+from repro.serve.batched_executor import JaxBatchedExecutor
+from repro.serve.jax_executor import JaxSlotExecutor
+
+FAMILIES = {
+    "smollm-135m": JaxBatchedExecutor,     # dense: paged decode
+    "whisper-medium": JaxSlotExecutor,     # encdec: encoder frames
+    "rwkv6-3b": JaxSlotExecutor,           # ssm: recurrent state
+}
+BATCH, N_REQ, PROMPT, MAX_NEW, DT = 4, 6, 8, 4, 0.25
 
 
-def test_pad_group_uses_sentinel_clones():
-    reqs = [Request(i, np.zeros(4, np.int32), 8) for i in range(2)]
-    padded = pad_group(reqs, 4)
-    assert len(padded) == 4
-    assert [r.rid for r in padded[:2]] == [0, 1]
-    assert all(r.is_pad for r in padded[2:])
-    # clones must not share mutable state with the real requests
-    padded[2].out_tokens.append(123)
-    assert reqs[0].out_tokens == []
-
-
-def test_pad_group_fills_tiny_tail_to_full_width():
-    """A tail smaller than half the batch still pads to full width (the
-    clone source cycles), keeping the compiled batch shape stable."""
-    reqs = [Request(0, np.zeros(4, np.int32), 8)]
-    padded = pad_group(reqs, 8)
-    assert len(padded) == 8
-    assert sum(r.is_pad for r in padded) == 7
-
-
-def test_pad_group_full_batch_unchanged():
-    reqs = [Request(i, np.zeros(4, np.int32), 8) for i in range(4)]
-    assert pad_group(reqs, 4) == reqs
-
-
-def test_pad_group_empty_group_raises():
-    """Regression: an empty group used to hit modulo-by-zero in the
-    clone-source cycle; now it is rejected up front."""
-    with pytest.raises(ValueError, match="empty"):
-        pad_group([], 4)
-
-
-def test_server_rejects_nonpositive_batch():
-    from repro.configs import get_smoke
-
-    with pytest.raises(ValueError, match="batch"):
-        Server(get_smoke("smollm-135m"), batch=0, max_len=12)
-
-
-@pytest.fixture(scope="module")
-def smoke_server():
-    from repro.configs import get_smoke
-
-    cfg = get_smoke("smollm-135m")
-    ledger = GoodputLedger(window=60.0)
-    server = Server(cfg, batch=4, max_len=12, ledger=ledger)
-    return cfg, server, ledger
-
-
-def _requests(cfg, n, prompt_len=8, max_new=4):
+def _requests(cfg, clock, n=N_REQ, max_new=MAX_NEW):
     rng = np.random.default_rng(0)
-    return [Request(i, rng.integers(0, cfg.vocab_size,
-                                    prompt_len).astype(np.int32),
-                    max_new, t_submit=time.monotonic())
+    t_submit = clock()
+    return [ServeRequest(rid=i, prompt_len=PROMPT, max_new=max_new,
+                         t_submit=t_submit,
+                         prompt=rng.integers(0, cfg.vocab_size, PROMPT)
+                         .astype(np.int32))
             for i in range(n)]
 
 
-def test_padded_tail_batch_not_double_counted(smoke_server):
-    """6 requests at batch 4: the tail batch carries 2 sentinel pads.
-    Before the fix the duplicated Request objects got tokens appended
-    twice and t_first/t_done overwritten, inflating throughput."""
-    cfg, server, _ = smoke_server
-    reqs = _requests(cfg, 6)
-    for i in range(0, len(reqs), 4):
-        server.run_batch(pad_group(reqs[i:i + 4], 4))
-    assert all(len(r.out_tokens) == r.max_new for r in reqs)
-    assert sum(len(r.out_tokens) for r in reqs) == 6 * 4
-    assert all(r.t_done >= r.t_first > 0 for r in reqs)
+def _serve(arch):
+    cfg = get_smoke(arch)
+    clock = TickClock(dt=DT)
+    reqs = _requests(cfg, clock)
+    engine, out = run_continuous_server(cfg, reqs, BATCH, slo_ttft=0.0,
+                                        slo_tpot=0.0, clock=clock)
+    return reqs, engine, out
 
 
-def test_serve_emits_all_accounting_phases(smoke_server):
-    cfg, server, ledger = smoke_server
-    before = ledger.n_events
-    reqs = _requests(cfg, 3)          # batch of 4 -> one pad slot
-    server.run_batch(pad_group(reqs, 4))
-    assert ledger.n_events > before
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def served(request):
+    return (request.param,) + _serve(request.param)
+
+
+def test_each_request_gets_max_new_tokens_counted_once(served):
+    """Six requests at batch 4: the second wave is two requests wide, and
+    each request's tokens are delivered and reported exactly once."""
+    arch, reqs, _, out = served
+    vocab = get_smoke(arch).vocab_size
+    assert [len(r.out_tokens) for r in reqs] == [MAX_NEW] * N_REQ
+    assert all(0 <= t < vocab for r in reqs for t in r.out_tokens)
+    assert out["requests"] == N_REQ
+    assert out["tokens"] == N_REQ * MAX_NEW
+    assert out["arch"] == arch
+
+
+def test_run_books_every_accounting_phase(served):
+    _, _, engine, out = served
+    ledger = engine.ledger
     for phase in (Phase.QUEUED, Phase.INIT, Phase.STEP, Phase.IDLE):
         assert ledger.phase_chip_time(phase) > 0.0, phase
-    bd = ledger.rg_breakdown()
-    assert "step" in bd and "idle" in bd
-    assert sum(bd.values()) == pytest.approx(1.0)
+    assert {"init", "step", "idle"} <= set(out["rg_breakdown"])
+    assert sum(out["rg_breakdown"].values()) == pytest.approx(1.0)
     # serve segment tagging feeds the fleet-wide phase_kind split (Fig. 15)
-    by = ledger.segment_report("phase_kind", {"serve": 1.0})
-    assert "serve" in by
+    assert "serve" in ledger.segment_report("phase_kind", {"serve": 1.0})
     # cross-layer provenance: serve events carry emitter=serve (trace
     # source) plus a canonical stack-layer tag for attribution
     assert "serve" in ledger.segment_report("emitter", {"serve": 1.0})
@@ -102,129 +78,88 @@ def test_serve_emits_all_accounting_phases(smoke_server):
     assert {"model", "scheduling"} <= layers
 
 
-def test_injected_tick_clock_makes_serve_accounting_deterministic():
-    """The determinism-audit fix for wall-clock reads: with a virtual
-    clock the serve emitter's interval stream — and hence the ledger
-    totals a recorded serve trace must reproduce — is identical across
-    runs."""
-    from repro.configs import get_smoke
-
-    cfg = get_smoke("smollm-135m")
-
-    def run_once():
-        clock = TickClock(dt=0.25)
-        ledger = GoodputLedger(window=60.0)
-        server = Server(cfg, batch=2, max_len=12,
-                        ledger=ledger, clock=clock)
-        reqs = [Request(i, np.full(8, i + 1, np.int32), 3,
-                        t_submit=clock()) for i in range(3)]
-        for i in range(0, len(reqs), 2):
-            server.run_batch(pad_group(reqs[i:i + 2], 2))
-        return ledger.totals()
-
-    first, second = run_once(), run_once()
-    assert first == second          # exact: every float bit-identical
-    assert first["n_events"] > 0
+def test_tick_clock_runs_are_bit_identical(served):
+    """With a virtual clock the report and the ledger's interval totals
+    are identical across runs, every float bit for bit."""
+    arch, reqs, engine, out = served
+    reqs2, engine2, out2 = _serve(arch)
+    assert out2 == out
+    assert engine2.ledger.totals() == engine.ledger.totals()
+    assert [r.out_tokens for r in reqs2] == [r.out_tokens for r in reqs]
 
 
-class CountingClock(TickClock):
-    """TickClock that also counts how many times it was read."""
-
-    def __init__(self, dt=0.25):
-        super().__init__(dt=dt)
-        self.reads = 0
-
-    def __call__(self):
-        self.reads += 1
-        return super().__call__()
-
-
-def _tick_server(batch=2, dt=0.25):
-    from repro.configs import get_smoke
-
-    cfg = get_smoke("smollm-135m")
-    clock = CountingClock(dt=dt)
-    ledger = GoodputLedger(window=60.0)
-    server = Server(cfg, batch=batch, max_len=12,
-                    ledger=ledger, clock=clock)
-    reqs = [Request(i, np.full(8, i + 1, np.int32), 3, t_submit=0.0)
-            for i in range(batch)]
-    return server, ledger, clock, reqs
-
-
-def test_run_batch_reads_clock_exactly_three_times():
-    """Regression (serve-clock skew): per-request clock reads used to
-    advance an injected TickClock mid-batch, so t_first/t_done drifted
-    past the emitted interval bounds.  A batch has exactly three time
-    boundaries — start, prefill end, decode end — and must read the
-    clock exactly once at each."""
-    server, _, clock, reqs = _tick_server()
-    server.run_batch(reqs)
-    assert clock.reads == 3
-    before = clock.reads
-    server.run_batch(reqs)
-    assert clock.reads - before == 3
-
-
-def test_request_timestamps_land_inside_emitted_intervals():
-    """t_first/t_done must be consistent with the intervals the emitter
-    books: with dt=0.25 the batch spans [t0, t0+0.5], t_first == t0+0.25
-    (prefill end) and t_done == t0+0.5 (decode end) for every request —
-    not one tick later per slot as under the per-request-read bug."""
-    server, ledger, _, reqs = _tick_server(batch=3)
-    server.run_batch(reqs)
-    t0, t1, t2 = 0.25, 0.5, 0.75
-    assert all(r.t_first == t1 for r in reqs)
-    assert all(r.t_done == t2 for r in reqs)
-    # and the per-slot phase intervals exactly tile batch x [t0, t2]
-    span_chip_time = server.batch * (t2 - t0)
-    booked = sum(ledger.phase_chip_time(p)
+def test_timestamps_lie_inside_the_run_and_capacity_is_its_span(served):
+    """Each op reads the clock once at its start and once after its host
+    sync, so under TickClock(dt) every op costs one dt: two waves of one
+    prefill and three decodes span 8 dt, the first wave's first tokens
+    land one dt in, and the slots' intervals tile batch x span."""
+    _, reqs, engine, out = served
+    t_start = reqs[0].t_submit
+    t_end = t_start + out["span"]
+    assert out["span"] == 8 * DT
+    assert [r.t_first for r in reqs[:BATCH]] == [t_start + DT] * BATCH
+    for r in reqs:
+        assert t_start < r.t_first <= r.t_done <= t_end
+    assert max(r.t_done for r in reqs) == t_end
+    assert out["capacity_chip_time"] == pytest.approx(BATCH * out["span"])
+    booked = sum(engine.ledger.phase_chip_time(p)
                  for p in (Phase.INIT, Phase.STEP, Phase.IDLE))
-    assert booked == pytest.approx(span_chip_time)
+    assert booked == pytest.approx(out["capacity_chip_time"])
+    assert 0.0 < out["goodput"]["SG"] <= 1.0
 
 
-def test_run_batch_rejects_wrong_width():
-    """Regression: self.batch was stored but never checked, silently
-    running whatever width it was handed (breaking capacity math)."""
-    server, _, _, reqs = _tick_server(batch=2)
-    with pytest.raises(ValueError, match="batch"):
-        server.run_batch(reqs[:1])
+def test_make_executor_picks_by_family(served):
+    arch, _, engine, out = served
+    assert type(engine.executor) is FAMILIES[arch]
+    assert out["kv_cache"]["block_tokens"] == BLOCK_TOKENS
+    if FAMILIES[arch] is JaxBatchedExecutor:
+        # the engine shares the executor's allocator: it is the gather map
+        assert engine.kv is engine.executor.kv
+        assert engine.executor.decode_compiles() == 1
 
 
-def test_server_no_longer_accepts_dead_prompt_len():
-    """Regression: Server(prompt_len=...) was accepted and ignored."""
-    from repro.configs import get_smoke
-
-    with pytest.raises(TypeError):
-        Server(get_smoke("smollm-135m"), batch=2, prompt_len=8, max_len=12)
-
-
-def test_capacity_derived_from_ledger_span():
-    """Regression: main() computed capacity as batch * (max t_done -
-    min t_submit) — mixing the request wall-clock base with the emitter
-    clock base and dividing by zero when they coincided.  Capacity now
-    comes from the server's own emitted span."""
-    server, ledger, _, reqs = _tick_server(batch=2)
-    assert server.capacity_chip_time() == 0.0   # degenerate: nothing run
-    server.run_batch(reqs)
-    # span is [first t0, last t2] on the injected clock: 0.25 -> 0.75
-    assert server.span() == pytest.approx(0.5)
-    assert server.capacity_chip_time() == pytest.approx(2 * 0.5)
-    rep = ledger.report(capacity_chip_time=server.capacity_chip_time())
-    assert 0.0 < rep.sg <= 1.0
-
-
-def test_degenerate_zero_span_guarded():
-    """A zero-dt clock collapses the span; throughput math must return
-    0.0 instead of raising ZeroDivisionError."""
-    from repro.configs import get_smoke
-    from repro.launch.serve import run_static_server
-
+def test_batch_zero_raises():
     cfg = get_smoke("smollm-135m")
-    reqs = [Request(i, np.full(8, i + 1, np.int32), 2, t_submit=0.0)
-            for i in range(2)]
-    _, out = run_static_server(cfg, reqs, batch=2, max_new=2, prompt_len=8,
-                               clock=TickClock(dt=0.0))
-    assert out["throughput_tok_s"] == 0.0
+    with pytest.raises(ValueError, match="batch"):
+        run_continuous_server(cfg, _requests(cfg, TickClock(dt=DT)), 0,
+                              slo_ttft=0.0, slo_tpot=0.0)
+
+
+def test_zero_dt_clock_reports_zero_throughput():
+    """A zero-dt clock collapses the span: the report's rates are 0.0,
+    not a ZeroDivisionError, and every token is still counted."""
+    cfg = get_smoke("smollm-135m")
+    clock = TickClock(dt=0.0)
+    reqs = _requests(cfg, clock, n=2, max_new=2)
+    _, out = run_continuous_server(cfg, reqs, 2, slo_ttft=0.0,
+                                   slo_tpot=0.0, clock=clock)
+    assert out["span"] == 0.0
     assert out["capacity_chip_time"] == 0.0
-    assert out["tokens_generated"] == 4
+    assert out["slo_goodput"] == 0.0
+    assert out["goodput"]["SG"] == 0.0
+    assert out["tokens"] == 4
+
+
+def _main(argv, capsys, monkeypatch):
+    # the CLI turns on the on-disk compile cache; keep the test hermetic
+    monkeypatch.setattr(serve, "enable_persistent_cache", lambda: None)
+    serve.main(argv)
+    return json.loads(capsys.readouterr().out)
+
+
+SMOKE_CLI = ["--arch", "smollm-135m", "--smoke", "--requests", "3",
+             "--batch", "2", "--prompt-len", "8", "--max-new", "3"]
+
+
+def test_main_prints_a_json_report(capsys, monkeypatch):
+    out = _main(SMOKE_CLI + ["--tick-dt", "0.25"], capsys, monkeypatch)
+    assert out["engine"] == "continuous"
+    assert out["arch"] == "smollm-135m"
+    assert (out["requests"], out["tokens"], out["n_slots"]) == (3, 9, 2)
+
+
+def test_main_spreads_bursty_arrivals(capsys, monkeypatch):
+    out = _main(SMOKE_CLI + ["--span", "4", "--arrival", "bursty"],
+                capsys, monkeypatch)
+    assert (out["requests"], out["tokens"]) == (3, 9)
+    assert out["span"] > 0.0

@@ -1,7 +1,6 @@
 """Continuous-batching engine tests: paged KV-cache allocator, interval
 partition/conservation properties, SLO-breach attribution, preemption
 (LOST) accounting, determinism, and the continuous-vs-static A/B."""
-import inspect
 import math
 
 import pytest
@@ -15,7 +14,7 @@ from repro.core.attribution import AttributionWaterfall
 from repro.core.goodput import (ALLOCATED_PHASES, PRODUCTIVE_PHASES, Layer,
                                 Phase, loss_bucket)
 from repro.core.ledger import GoodputLedger
-from repro.serve import (FLASH_ATTENTION_BLOCK_K, ContinuousServeEngine,
+from repro.serve import (BLOCK_TOKENS, ContinuousServeEngine,
                          OutOfBlocksError, PagedKVCache, ServeRequest,
                          ServeSLO, SimulatedExecutor, run_static,
                          synthetic_requests)
@@ -24,14 +23,16 @@ from repro.serve import (FLASH_ATTENTION_BLOCK_K, ContinuousServeEngine,
 # ---- paged KV cache ------------------------------------------------------
 
 def test_kv_block_size_mirrors_flash_attention_block_k():
-    """The allocator's default block granularity is the Pallas flash
-    attention kernel's key-block tile, so paged decode over block tables
-    feeds the kernel whole tiles."""
-    from repro.kernels.flash_attention.flash_attention import flash_attention
-
-    sig = inspect.signature(flash_attention)
-    assert FLASH_ATTENTION_BLOCK_K == sig.parameters["block_k"].default
-    assert PagedKVCache(n_blocks=2).block_tokens == FLASH_ATTENTION_BLOCK_K
+    """One page size: the allocator's default and the cache the engine
+    builds for itself (the per-slot executor's) both use BLOCK_TOKENS,
+    with one page a slot per BLOCK_TOKENS tokens of the longest
+    request."""
+    assert PagedKVCache(n_blocks=2).block_tokens == BLOCK_TOKENS
+    eng = ContinuousServeEngine(3, SimulatedExecutor())
+    eng.run([ServeRequest(rid=0, prompt_len=BLOCK_TOKENS, max_new=2),
+             ServeRequest(rid=1, prompt_len=8, max_new=4)])
+    assert eng.kv.block_tokens == BLOCK_TOKENS
+    assert eng.kv.n_blocks == 3 * 2
 
 
 def test_kv_allocate_append_free_roundtrip():

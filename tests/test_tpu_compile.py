@@ -17,10 +17,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.paged_attention.ops import (DEFAULT_BLOCK_TOKENS,
-                                               paged_attention_decode)
+from repro.kernels.paged_attention.ops import paged_attention_decode
 from repro.models import model, transformer
 from repro.models.init import abstract_params
+from repro.serve.kv_cache import BLOCK_TOKENS
 
 W = 8           # batch width of the serving executor
 NB = 4          # pages per block-table row
@@ -67,7 +67,7 @@ def test_paged_kernel_compiles_at_smollm_widths(one_chip, no_persistent_cache,
                                                  window):
     cfg = get_config("smollm-135m")
     hkv, d = cfg.num_kv_heads, cfg.head_dim
-    pages = transformer.paged_kv_shape(cfg, W * NB + 1, DEFAULT_BLOCK_TOKENS)
+    pages = transformer.paged_kv_shape(cfg, W * NB + 1, BLOCK_TOKENS)
     args = (_spec((W, cfg.num_heads, d), jnp.bfloat16, one_chip),
             _spec((W, hkv, d), jnp.bfloat16, one_chip),
             _spec((W, hkv, d), jnp.bfloat16, one_chip),
@@ -88,7 +88,7 @@ def _paged_step(cfg, one_chip, width, pages_per_row):
     params = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip),
                           abstract_params(cfg))
     pages = transformer.paged_kv_shape(cfg, width * pages_per_row + 1,
-                                       DEFAULT_BLOCK_TOKENS)
+                                       BLOCK_TOKENS)
     args = (params,
             _spec((width,), jnp.int32, one_chip),
             _spec((width,), jnp.int32, one_chip),
@@ -194,7 +194,7 @@ def test_qwen_cut_longest_prefill_and_page_scatter_fit(one_chip,
                           abstract_params(cfg))
     tokens = {"tokens": _spec((1, 1500), jnp.int32, one_chip)}
     pages = transformer.paged_kv_shape(cfg, 32 * 16 + 1,
-                                       DEFAULT_BLOCK_TOKENS)
+                                       BLOCK_TOKENS)
 
     def prefill(p, b):
         return transformer.prefill(p, b, cfg, max_len=2012)
@@ -238,6 +238,6 @@ def test_paged_kernel_scratch_fits_vmem(one_chip, no_persistent_cache,
     cfg = dataclasses.replace(get_config(arch), **cut)
     _, compiled = _paged_step(cfg, one_chip, width, 16)
     vmem = _kernel_vmem(compiled.as_text())
-    slots = 2 * 2 * cfg.num_kv_heads * cfg.head_dim * DEFAULT_BLOCK_TOKENS \
+    slots = 2 * 2 * cfg.num_kv_heads * cfg.head_dim * BLOCK_TOKENS \
         * jnp.dtype(cfg.compute_dtype).itemsize
     assert slots <= vmem <= VMEM_SCOPED, (slots, vmem)
